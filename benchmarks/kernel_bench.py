@@ -233,82 +233,58 @@ def nsga2_bench(pop: int = 64, n_images: int = 64) -> dict:
 def nsga2_sharded_bench(
     pop: int = 128,
     n_images: int = 16,
-    device_counts: tuple = (1, 2, 4),
+    device_counts: tuple | None = None,
     iters: int = 12,
     warmup: int = 3,
 ) -> dict:
-    """Population-sharded NSGA-II evaluation throughput per host-device count.
+    """Population-sharded NSGA-II evaluation throughput per device count.
 
-    Each device count runs in its own subprocess (like tests/test_distribution
-    does) because ``--xla_force_host_platform_device_count`` must be set before
-    any jax import. The single-device baseline keeps XLA's normal intra-op
-    threading — an honest comparison — so the default shape is the search
-    sweet spot where sharding wins on this 2-core box: a small inner-loop
-    image subset (many generations over few images is the NSGA-II regime)
-    and a deep population, i.e. many genome blocks of mostly-serialized
-    small ops that one device scans sequentially but a mesh splits.
-    Genome scores are bitwise identical across device counts (the engine's
-    CRN invariant; asserted by tests/test_engine_sharded.py), so the sweep
-    is a pure throughput comparison. Returns per-device-count genomes/sec
-    columns plus the 2-device speedup (persisted to BENCH_nsga2.json).
+    One process sweeps the device counts over ``make_pop_mesh(n)`` subsets
+    of ``jax.devices()`` — on a chip host one process holds every chip. The
+    default sweep is every power of two up to the devices present; whoever
+    launches the process chooses them (on a CPU host, forced host devices:
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before the run).
+    A requested count above the devices present raises. Genome scores are
+    bitwise identical across device counts (the engine's CRN invariant;
+    asserted by tests/test_engine_sharded.py), so the sweep is a pure
+    throughput comparison. Returns per-device-count genomes/sec columns plus
+    the 2-device speedup (persisted to BENCH_nsga2.json).
     """
-    import json
-    import os
-    import subprocess
-    import sys
-    import textwrap
+    from repro.experiments import paper_cnn
+    from repro.models import cnn
+    from repro.parallel import sharding as shd
 
-    snippet = textwrap.dedent(f"""
-        import json, time
-        import numpy as np, jax
-        from repro.experiments import paper_cnn
-        from repro.models import cnn
-        from repro.parallel import sharding as shd
-
-        nd = int(__import__("os").environ["BENCH_N_DEVICES"])
-        try:
-            params = paper_cnn.load_params()
-        except FileNotFoundError:
-            params = cnn.init_params(jax.random.PRNGKey(0))
-        mesh = shd.make_pop_mesh(nd) if nd > 1 else None
-        ev = paper_cnn.make_batched_evaluator(params, {n_images}, mesh=mesh)
-        rng = np.random.default_rng(0)
-        g = rng.integers(1, 9, ({pop}, cnn.N_SLOTS)).astype(np.int32)
-        key = jax.random.PRNGKey(42)
-        for _ in range({warmup}):
-            ev(g, key)
-        t0 = time.time()
-        for _ in range({iters}):
-            ev(g, key)
-        sec = (time.time() - t0) / {iters}
-        print(json.dumps({{"n_devices": nd, "sec_per_generation": sec,
-                           "genomes_per_sec": {pop} / sec}}))
-    """)
-
+    n_avail = len(jax.devices())
+    if device_counts is None:
+        device_counts = tuple(1 << i for i in range(n_avail.bit_length()))
+    try:
+        params = paper_cnn.load_params()
+    except FileNotFoundError:
+        params = cnn.init_params(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    g = rng.integers(1, 9, (pop, cnn.N_SLOTS)).astype(np.int32)
+    key = jax.random.PRNGKey(42)
     out: dict = {
         "pop_size": pop,
         "n_images": n_images,
         "iters": iters,
+        "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
         "per_device_count": {},
     }
-    src = str(__import__("pathlib").Path(__file__).resolve().parents[1] / "src")
     for nd in device_counts:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={nd}"
-        env["BENCH_N_DEVICES"] = str(nd)
-        env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-        try:
-            proc = subprocess.run([sys.executable, "-c", snippet], env=env,
-                                  capture_output=True, text=True, timeout=600)
-        except subprocess.TimeoutExpired:
-            print(f"nsga2_sharded_bench nd={nd} TIMED OUT (600s); skipping")
-            continue
-        if proc.returncode != 0:
-            print(f"nsga2_sharded_bench nd={nd} FAILED:\n{proc.stdout}{proc.stderr}")
-            continue
-        row = json.loads(proc.stdout.strip().splitlines()[-1])
+        mesh = shd.make_pop_mesh(nd) if nd > 1 else None
+        ev = paper_cnn.make_batched_evaluator(params, n_images, mesh=mesh)
+        for _ in range(warmup):
+            ev(g, key)
+        t0 = time.time()
+        for _ in range(iters):
+            ev(g, key)
+        sec = (time.time() - t0) / iters
+        row = {"n_devices": nd, "sec_per_generation": sec,
+               "genomes_per_sec": pop / sec}
         out["per_device_count"][str(nd)] = row
-        print(f"nsga2_sharded_pop{pop}_dev{nd},{row['sec_per_generation']*1e6:.1f},"
+        print(f"nsga2_sharded_pop{pop}_dev{nd},{sec*1e6:.1f},"
               f"{row['genomes_per_sec']:.1f}_genomes_per_sec")
     base = out["per_device_count"].get("1")
     two = out["per_device_count"].get("2")

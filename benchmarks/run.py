@@ -23,24 +23,32 @@ an independent draw against the committed artifacts/audit_baseline.json
 (a fresh baseline lands next to it for --update adoption).
 
 --smoke runs the runner-sized subset the PR gate measures (engine,
-foundry, codesign, the 1/2-device sharded-search sweep — written to
-BENCH_nsga2_sharded.json — and the serving load bench) and skips the
-paper-table sections.
+foundry, codesign, the sharded-search sweep over the devices present —
+written to BENCH_nsga2_sharded.json — and the serving load bench) and skips
+the paper-table sections.
+
+Every section runs; the process exits non-zero if any section raised.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import pathlib
+import sys
 import traceback
+
+_FAILED: list[str] = []
 
 
 def _section(title: str, fn):
+    """Run one section; a failure is printed, recorded, and the run goes on
+    (main exits non-zero at the end)."""
     print(f"\n{'=' * 72}\n{title}\n{'=' * 72}")
     try:
         return fn()
     except Exception:
         traceback.print_exc()
+        _FAILED.append(title)
         return None
 
 
@@ -73,8 +81,8 @@ def smoke(out_dir: pathlib.Path) -> None:
         "Codesign — two-level placement+interleaving search throughput",
         lambda: _codesign_bench_traced(out_dir)))
     _write(out_dir, "BENCH_nsga2_sharded.json", _section(
-        "NSGA-II sharded search — genomes/sec per host-device count",
-        lambda: kernel_bench.nsga2_sharded_bench(device_counts=(1, 2))))
+        "NSGA-II sharded search — genomes/sec per device count",
+        kernel_bench.nsga2_sharded_bench))
     _write(out_dir, "BENCH_serve.json", _section(
         "Serving — batched vs per-slot mixed-tier load (smoke)",
         lambda: _serve_bench(requests=8, max_new=24, slots=4,
@@ -156,7 +164,7 @@ def full(out_dir: pathlib.Path) -> None:
         "NSGA-II search throughput — batched vs per-individual evaluation",
         kernel_bench.nsga2_bench)
     sharded_metrics = _section(
-        "NSGA-II sharded search — genomes/sec per host-device count",
+        "NSGA-II sharded search — genomes/sec per device count",
         kernel_bench.nsga2_sharded_bench)
     if nsga2_metrics is not None:
         if sharded_metrics is not None:
@@ -173,7 +181,10 @@ def full(out_dir: pathlib.Path) -> None:
              roofline_summary.main)
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> int:
+    from repro import compile_cache
+
+    compile_cache.enable()
     default_out = pathlib.Path(__file__).resolve().parent.parent / "artifacts"
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
@@ -193,7 +204,11 @@ def main(argv=None) -> None:
         smoke(args.out)
     else:
         full(args.out)
+    if _FAILED:
+        print(f"\n{len(_FAILED)} section(s) failed: {_FAILED}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

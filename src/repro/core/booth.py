@@ -87,8 +87,10 @@ def booth_ppm(a24, b24):
     corr = ((neg_count[..., None] >> jnp.arange(4, dtype=jnp.int32)) & 1).astype(
         jnp.int32
     )
-    corr_row = jnp.zeros(rows.shape[:-2] + (N_COLS,), dtype=jnp.int32)
-    corr_row = corr_row.at[..., :4].set(corr)
+    lead = rows.shape[:-2]
+    corr_row = jnp.concatenate(
+        [jnp.broadcast_to(corr, lead + (4,)),
+         jnp.zeros(lead + (N_COLS - 4,), jnp.int32)], axis=-1)
 
     return jnp.concatenate([rows, corr_row[..., None, :]], axis=-2)
 

@@ -46,6 +46,30 @@ def cout42(x1, x2, x3):
     return (x1 & x2) | ((x1 ^ x2) & x3)
 
 
+def _candidates(x1, x2, x3, x4, cin, one):
+    """(sum, carry) of every compressor design, keyed by code.
+
+    ``one`` is the all-ones word of the operands' encoding: 1 for {0,1} bit
+    arrays, 0xFFFFFF for the packed 24-column limbs of core/fp32_mul.py —
+    every term is bitwise, so one truth table serves both spellings.
+    """
+    t = x1 ^ x2 ^ x3
+    sx = t ^ x4
+    sum_exact = sx ^ cin
+    carry_exact = (sx & cin) | (t & x4)
+    return {
+        EXACT: (sum_exact, carry_exact),
+        # PC1: or an extra positive term into sum.
+        PC1: (sum_exact | (x1 & x2) | (x3 & x4), carry_exact),
+        # PC2: or an extra positive term into carry.
+        PC2: (sum_exact, carry_exact | ((x1 ^ x2) & x3 & x4)),
+        # NC1: drop the carry-in entirely.
+        NC1: (sx, t & x4),
+        # NC2: NC1 plus a dropped carry on the all-ones pattern.
+        NC2: (sx, (t & x4) & (one ^ (x1 & x2 & x3 & x4))),
+    }
+
+
 def compress42(x1, x2, x3, x4, cin, code):
     """Vectorized 4:2 compression with per-element compressor selection.
 
@@ -56,37 +80,25 @@ def compress42(x1, x2, x3, x4, cin, code):
     Returns:
       (sum, carry, cout) int32 {0,1} arrays.
     """
-    t = x1 ^ x2 ^ x3
-    sx = t ^ x4
-    cout = cout42(x1, x2, x3)
-
-    sum_exact = sx ^ cin
-    carry_exact = (sx & cin) | (t & x4)
-
-    # PC1: or an extra positive term into sum.
-    sum_pc1 = sum_exact | (x1 & x2) | (x3 & x4)
-    carry_pc1 = carry_exact
-    # PC2: or an extra positive term into carry.
-    sum_pc2 = sum_exact
-    carry_pc2 = carry_exact | ((x1 ^ x2) & x3 & x4)
-    # NC1: drop the carry-in entirely.
-    sum_nc1 = sx
-    carry_nc1 = t & x4
-    # NC2: NC1 plus a dropped carry on the all-ones pattern.
-    sum_nc2 = sx
-    carry_nc2 = (t & x4) & (1 - (x1 & x2 & x3 & x4))
-
+    cand = _candidates(x1, x2, x3, x4, cin, 1)
+    s, c = cand[EXACT]
     # Branch-free selection (codes are data, may vary per element).
-    def sel(e, p1, p2, n1, n2):
-        out = jnp.where(code == PC1, p1, e)
-        out = jnp.where(code == PC2, p2, out)
-        out = jnp.where(code == NC1, n1, out)
-        out = jnp.where(code == NC2, n2, out)
-        return out
+    for k in (PC1, PC2, NC1, NC2):
+        s = jnp.where(code == k, cand[k][0], s)
+        c = jnp.where(code == k, cand[k][1], c)
+    return s, c, cout42(x1, x2, x3)
 
-    s = sel(sum_exact, sum_pc1, sum_pc2, sum_nc1, sum_nc2)
-    c = sel(carry_exact, carry_pc1, carry_pc2, carry_nc1, carry_nc2)
-    return s, c, cout
+
+def compress42_masked(x1, x2, x3, x4, cin, masks, one):
+    """compress42 over packed columns: ``masks`` maps each approximate code
+    (PC1, PC2, NC1, NC2) to the word of columns that use it; the remaining
+    columns are exact. Returns (sum, carry) words (cout is ``cout42``)."""
+    cand = _candidates(x1, x2, x3, x4, cin, one)
+    s, c = cand[EXACT]
+    for k, m in masks.items():
+        s = s ^ ((s ^ cand[k][0]) & m)
+        c = c ^ ((c ^ cand[k][1]) & m)
+    return s, c
 
 
 def compressor_value_error(x1, x2, x3, x4, cin, code):

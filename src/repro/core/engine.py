@@ -966,12 +966,10 @@ class AMEngine:
         leading axes for pop_args, replicated rep_args and outputs sharded."""
         from jax.sharding import PartitionSpec as PS
 
-        from repro.parallel import sharding as shd
-
         sp = PS(self.pop_axis_name)
         in_specs = (sp,) * len(pop_args) + (PS(),) * len(rep_args)
         out_specs = (sp,) * n_outs if n_outs > 1 else sp
-        f = shd.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+        f = jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                           out_specs=out_specs, check_vma=False)
         return f(*pop_args, *rep_args)
 

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import booth
+from repro.core import compressors as C
 from repro.core import schemes
 from repro.core.compressors import compress42, cout42
 
@@ -190,7 +191,7 @@ def fp32_multiply_variant(a, b, variant: str):
     return fp32_multiply(a, b, jnp.asarray(schemes.scheme_map(variant)))
 
 
-def fp32_multiply_interleaved(a, b, variant_ids, scheme_stack=None):
+def fp32_multiply_interleaved(a, b, variant_ids):
     """Multiply with a *per-element* variant assignment.
 
     Args:
@@ -198,20 +199,185 @@ def fp32_multiply_interleaved(a, b, variant_ids, scheme_stack=None):
       variant_ids: int32 (...,) in [0, N_VARIANTS) broadcastable to a's
         shape; 0 means exact, 1..8 the paper's AMs, 9.. foundry-registered
         variants (schemes.VARIANTS order).
-      scheme_stack: optional (N_VARIANTS, 3, 48) int32 code stack; pass
-        explicitly from Pallas kernel bodies (kernels cannot capture array
-        constants) — and from any caller holding a jitted closure across
-        foundry registrations, so the live stack is a traced operand.
     Returns:
       float32 (...,).
 
     This is the paper's interleaving mechanism: each multiplier slot carries
-    its own variant. Implemented as a gather of (3, 48) code maps.
+    its own variant. Implemented as a gather of (3, 48) code maps from the
+    live registry (the XLA oracle's spelling; the Pallas kernels use the
+    packed emulation below with per-slot column masks).
     """
-    if scheme_stack is None:
-        scheme_stack = jnp.asarray(schemes.scheme_stack())  # (N_VARIANTS, 3, 48)
+    scheme_stack = jnp.asarray(schemes.scheme_stack())  # (N_VARIANTS, 3, 48)
     codes = scheme_stack[jnp.asarray(variant_ids, _I32)]  # (..., 3, 48)
     return fp32_multiply(a, b, codes)
+
+
+# ---------------------------------------------------------------------------
+# Packed emulation: the Pallas kernels' spelling
+# ---------------------------------------------------------------------------
+#
+# The same datapath with each 48-column row held as two int32 limbs of 24
+# columns (lo = columns 0..23, hi = 24..47) instead of a trailing 48-wide
+# bit axis. Every column operation of the tree is bitwise, so one limb pair
+# carries all 48 columns at once, and a variant's per-column compressor
+# choice becomes a column mask per (stage, approximate code): 3 stages x 4
+# codes x 2 limbs = 24 int32 words (code_masks). All ops are elementwise
+# int32 on arrays of the operands' shape — no gather, scatter or minor bit
+# axis — which is what Mosaic lowers, at ~1/16 of the bit tensor's live
+# bytes per multiply. Bit-identical to fp32_multiply (tests/test_fp32_mul.py).
+
+_M24 = (1 << 24) - 1
+_M23 = (1 << 23) - 1
+_APPROX_CODES = (C.PC1, C.PC2, C.NC1, C.NC2)
+N_MASK_WORDS = schemes.N_STAGES * len(_APPROX_CODES) * 2
+
+
+def code_masks(scheme_maps) -> np.ndarray:
+    """(..., 3, 48) compressor-code maps -> (..., 24) int32 column masks.
+
+    Word ``(stage * 4 + j) * 2 + limb`` holds the columns of limb ``limb``
+    whose stage-``stage`` compressor is ``_APPROX_CODES[j]``.
+    """
+    maps = np.asarray(scheme_maps, np.int64)
+    weights = np.int64(1) << np.arange(24, dtype=np.int64)
+    words = []
+    for stage in range(schemes.N_STAGES):
+        for code in _APPROX_CODES:
+            hit = maps[..., stage, :] == code  # (..., 48)
+            words.append((hit[..., :24] * weights).sum(-1))
+            words.append((hit[..., 24:] * weights).sum(-1))
+    return np.stack(words, axis=-1).astype(np.int32)
+
+
+def mask_stack() -> np.ndarray:
+    """(N_VARIANTS, 24) code masks of the live registry, by variant id."""
+    return code_masks(schemes.scheme_stack())
+
+
+def _srl(x, n):
+    return jax.lax.shift_right_logical(x, jnp.asarray(n, _I32))
+
+
+def _shl1_packed(lo, hi):
+    """_shift_left_1 on a limb pair (bit 47 falls off)."""
+    return (lo << 1) & _M24, ((hi << 1) | (lo >> 23)) & _M24
+
+
+def booth_packed(a, b):
+    """Operand half of the packed emulation: IEEE fields of a and b and the
+    10 Booth partial-product rows of man(a) * man(b) as limb pairs. It does
+    not read the scheme, so a caller emulating several variants on one
+    operand stream computes it once (kernels/bitexact_emulator.py)."""
+    ba = jax.lax.bitcast_convert_type(jnp.asarray(a, jnp.float32), _I32)
+    bb = jax.lax.bitcast_convert_type(jnp.asarray(b, jnp.float32), _I32)
+    ea, eb = (ba >> 23) & 0xFF, (bb >> 23) & 0xFF
+    ma, mb = ba & _M23, bb & _M23
+    man_a = jnp.where(ea > 0, ma | (1 << 23), ma)
+    man_b = jnp.where(eb > 0, mb | (1 << 23), mb)
+
+    def bit(j):
+        return (man_b >> j) & 1 if 0 <= j <= 23 else 0
+
+    rows = []
+    n_neg = 0
+    for i in range(booth.N_DIGITS):
+        d = bit(3 * i - 1) + bit(3 * i) + 2 * bit(3 * i + 1) - 4 * bit(3 * i + 2)
+        neg = d < 0
+        mag = jnp.abs(d) * man_a  # < 2^27
+        sh = 3 * i  # <= 24: the low limb's bits wrap off the top harmlessly
+        lo = (mag << sh) & _M24
+        hi = (mag >> (24 - sh)) & _M24
+        rows.append((jnp.where(neg, lo ^ _M24, lo), jnp.where(neg, hi ^ _M24, hi)))
+        n_neg = n_neg + neg.astype(_I32)
+    rows.append((n_neg + jnp.zeros_like(man_a), jnp.zeros_like(man_a)))
+    return (ba, ea, ma, bb, eb, mb), rows
+
+
+def _stage_packed(r1, r2, r3, r4, masks):
+    out = []
+    for limb in range(2):
+        x1, x2, x3, x4 = r1[limb], r2[limb], r3[limb], r4[limb]
+        out.append(C.cout42(x1, x2, x3))
+    cin = _shl1_packed(*out)
+    s, c = [], []
+    for limb in range(2):
+        sl, cl = C.compress42_masked(
+            r1[limb], r2[limb], r3[limb], r4[limb], cin[limb],
+            {k: masks[j][limb] for j, k in enumerate(_APPROX_CODES)}, _M24)
+        s.append(sl)
+        c.append(cl)
+    return tuple(s), _shl1_packed(*c)
+
+
+def _ilog2_24(x):
+    """floor(log2(x)) for 0 < x < 2^24 (exact in f32), via its exponent."""
+    f = jax.lax.bitcast_convert_type(x.astype(jnp.float32), _I32)
+    return (f >> 23) - 127
+
+
+def multiply_packed(fields, rows, masks):
+    """Scheme half of the packed emulation: the compressor tree, final add,
+    normalization and IEEE specials for one variant's ``masks`` (24 words,
+    each broadcastable against the operands). Returns float32."""
+    ba, ea, ma, bb, eb, mb = fields
+    m = [(masks[2 * j], masks[2 * j + 1]) for j in range(N_MASK_WORDS // 2)]
+    sA, cA = _stage_packed(rows[0], rows[1], rows[2], rows[3], m[0:4])
+    sB, cB = _stage_packed(rows[4], rows[5], rows[6], rows[7], m[0:4])
+    s1, k1 = _stage_packed(sA, cA, sB, cB, m[4:8])
+    s2, k2 = _stage_packed(s1, k1, rows[8], rows[9], m[8:12])
+    lo, hi = booth.limbs_add_mod48(s2[0], s2[1], k2[0], k2[1])
+
+    is_zero_prod = (lo == 0) & (hi == 0)
+    msb = jnp.where(hi > 0, 24 + _ilog2_24(jnp.maximum(hi, 1)),
+                    _ilog2_24(jnp.maximum(lo, 1)))
+    msb = jnp.where(is_zero_prod, booth.N_COLS - 1, msb)
+    # The 23 product bits below the leading one (truncation rounding).
+    sr = msb - 23  # in [-23, 24]
+    man23 = jnp.where(
+        sr >= 24, hi,
+        jnp.where(sr >= 0,
+                  _srl(lo, jnp.clip(sr, 0, 23)) | (hi << jnp.clip(24 - sr, 1, 24)),
+                  lo << jnp.clip(-sr, 0, 23))) & _M23
+
+    sign = _srl(ba ^ bb, 31)
+    ea_eff = jnp.where(ea > 0, ea - 127, -126)
+    eb_eff = jnp.where(eb > 0, eb - 127, -126)
+    e_biased = ea_eff + eb_eff + (msb - 46) + 127
+    overflow = e_biased >= 255
+    underflow = (e_biased <= 0) | is_zero_prod  # FTZ on subnormal outputs
+
+    sbit = sign << 31
+    zero = sbit
+    inf = sbit | (255 << 23)
+    out = sbit | (jnp.clip(e_biased, 1, 254) << 23) | man23
+    out = jnp.where(underflow, zero, out)
+    out = jnp.where(overflow, inf, out)
+
+    a_nan = (ea == 255) & (ma != 0)
+    b_nan = (eb == 255) & (mb != 0)
+    a_inf = (ea == 255) & (ma == 0)
+    b_inf = (eb == 255) & (mb == 0)
+    a_zero = (ea == 0) & (ma == 0)
+    b_zero = (eb == 0) & (mb == 0)
+    nan_out = a_nan | b_nan | (a_inf & b_zero) | (b_inf & a_zero)
+    inf_out = (a_inf | b_inf) & ~nan_out
+    zero_out = (a_zero | b_zero) & ~nan_out
+    out = jnp.where(zero_out, zero, out)
+    out = jnp.where(inf_out, inf, out)
+    out = jnp.where(nan_out, _QNAN_BITS, out)
+    return jax.lax.bitcast_convert_type(out, jnp.float32)
+
+
+# Bits of jnp.nan as float32: the NaN fp32_multiply returns.
+_QNAN_BITS = 0x7FC00000
+
+
+def fp32_multiply_packed(a, b, masks):
+    """fp32_multiply(a, b, codes) with ``masks = code_masks(codes)`` given as
+    24 words broadcastable against a and b (a sequence, or an array whose
+    leading axis is the word index)."""
+    fields, rows = booth_packed(a, b)
+    return multiply_packed(fields, rows, masks)
 
 
 # jit'd conveniences for benchmarking / batch evaluation --------------------

@@ -266,10 +266,8 @@ def make_batched_evaluator(
                 n_correct, name="paper_cnn.batched_evaluator")
         from jax.sharding import PartitionSpec as P
 
-        from repro.parallel import sharding as shd
-
         sp = P(pop_axis_name)
-        return watchdog.watch_jit(shd.shard_map(
+        return watchdog.watch_jit(jax.shard_map(
             n_correct, mesh=mesh, in_specs=(sp, sp, sp, sp, P()),
             out_specs=sp, check_vma=False),
             name="paper_cnn.batched_evaluator")

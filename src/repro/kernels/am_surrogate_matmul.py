@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import platform
+
 # (bm, bk, bn) fallback; callers should take blocks from the shared chooser
 # (kernels/ops.py choose_block, kind="surrogate_matmul").
 DEFAULT_BLOCK = (128, 128, 128)
@@ -63,13 +65,18 @@ def _kernel(x_ref, w_ref, mu_ref, sg_ref, mean_ref, var_ref):
     var_ref[...] += jax.lax.dot(x * x, w_var, preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def am_surrogate_matmul_kernel(x, w, mu, sg, *, block=DEFAULT_BLOCK, interpret=True):
+def am_surrogate_matmul_kernel(x, w, mu, sg, *, block=DEFAULT_BLOCK):
     """Fused (mean, var) AM matmul.
 
     x: (M, K); w, mu, sg: (K, N). M, K, N must divide by the block shape.
     Returns (mean, var), both (M, N) f32.
     """
+    return _am_surrogate_matmul_jit(x, w, mu, sg, block=tuple(block),
+                                    interpret=platform.interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _am_surrogate_matmul_jit(x, w, mu, sg, *, block, interpret):
     m, k = x.shape
     n = w.shape[1]
     bm, bk, bn = block
@@ -110,14 +117,19 @@ def _folded_kernel(x_ref, wm_ref, wv_ref, mean_ref, var_ref):
                                 preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def am_surrogate_matmul_folded_kernel(x, w_mean, w_var, *, block=DEFAULT_BLOCK,
-                                      interpret=True):
+def am_surrogate_matmul_folded_kernel(x, w_mean, w_var, *, block=DEFAULT_BLOCK):
     """(mean, var) AM matmul over pre-folded weights.
 
     x: (M, K); w_mean, w_var: (K, N), already carrying the moment transforms
     (engine.fold_matmul_weights). Dims must divide by the block shape.
     """
+    return _am_surrogate_matmul_folded_jit(
+        x, w_mean, w_var, block=tuple(block),
+        interpret=platform.interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _am_surrogate_matmul_folded_jit(x, w_mean, w_var, *, block, interpret):
     m, k = x.shape
     n = w_mean.shape[1]
     bm, bk, bn = block
@@ -184,15 +196,22 @@ def _epilogue_kernel_pop(x_ref, wm_ref, wv_ref, z_ref, out_ref, var_ref,
         out_ref[0] += z_ref[...] * jnp.sqrt(jnp.maximum(var_ref[0], 0.0))
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def am_surrogate_matmul_epilogue_kernel(x, w_mean, w_var, z, *,
-                                        block=DEFAULT_BLOCK, interpret=True):
+                                        block=DEFAULT_BLOCK):
     """One-launch surrogate matmul: out = x@wm + z*sqrt(max(x^2@wv, 0)).
 
     x: (M, K) or (P, M, K); w_mean, w_var: (K, N) or (P, K, N); z: (M, N),
     shared across the population axis (the engine's CRN invariant). Dims
     must divide by the block shape. Returns (P?, M, N) f32.
     """
+    return _am_surrogate_matmul_epilogue_jit(
+        x, w_mean, w_var, z, block=tuple(block),
+        interpret=platform.interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _am_surrogate_matmul_epilogue_jit(x, w_mean, w_var, z, *, block,
+                                      interpret):
     pop = w_mean.ndim == 3
     pop_x = x.ndim == 3
     m, k = x.shape[-2:]

@@ -2,21 +2,21 @@
 
 NHWC, VALID, stride 1. Each (filter, ky, kx) tap carries its own multiplier
 variant (slot map shared across input channels, exactly the paper's 198-slot
-scheme for the 22x3x3 CNN). The kernel tiles the batch dimension; within a
-program the 3x3 taps are unrolled (static Python loop — 9 steps) and each tap
-does an emulated-AM multiply of the (bh, ho, wo, Cin) patch against the
-(F, Cin) tap weights, vectorized over filters.
+scheme for the 22x3x3 CNN).
 
-VMEM sizing (paper CNN, bh=1): the per-tap PPM bit tensor is
-(ho*wo, Cin, F, 10, 48) int32 = (900, 3, 12, 480)*4 B ~= 59 MiB — too big in
-one shot, so the tap loop additionally chunks filters in groups (FG=4 on the
-paper CNN): (900, 3, 4, 480)*4 B ~= 20 MiB per chunk. That is the FULL bit
-tensor for a chunk; the pipeline streams it through the emulation stages, so
-the live working set stays inside the ~16 MiB v5e VMEM envelope (the shared
-chooser budgets 20 MiB of nominal tensor per chunk for exactly this reason).
-Grid iterates taps sequentially so only one chunk is live at a time. The
-group size comes from kernels/ops.py choose_block(kind="bitexact_conv");
-FILTER_GROUP is the paper-CNN fallback.
+Layout: the wrapper lays the input out as im2col planes, one per (tap,
+input channel), with every output position of the batch flattened and
+padded onto (rows, 128) lanes. A grid program takes ``row_block`` rows of
+every plane and, for each filter, tap and channel, runs the packed
+emulation (core/fp32_mul.py::fp32_multiply_packed) of the plane against the
+scalar tap weight — elementwise int32 work on one (row_block, 128) tile.
+Tap weights and the per-(filter, tap) column masks sit in SMEM. The paper
+CNN's planes are 900 (layer 1) and 169 (layer 2) positions per image, so
+the lanes are dense whatever the layer.
+
+Accumulation order (shared with kernels/ref.py::am_conv2d_bitexact_ref):
+per output, taps in (ky, kx) order, each tap adding the sequential left fold
+of its channel products from +0.0.
 """
 from __future__ import annotations
 
@@ -25,81 +25,79 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import fp32_mul, schemes
+from repro.core import fp32_mul
+from repro.kernels import platform
 
-FILTER_GROUP = 4
-
-
-def _make_kernel(kh: int, kw: int, f_total: int, filter_group: int):
-    def _kernel(x_ref, w_ref, vid_ref, stack_ref, o_ref):
-        x = x_ref[...]  # (bh, H, W, Cin)
-        w = w_ref[...]  # (F, kh, kw, Cin)
-        vids = vid_ref[...]  # (F, kh, kw)
-        stack = stack_ref[...]  # (9, 3, 48)
-        bh, h, wd, cin = x.shape
-        ho, wo = h - kh + 1, wd - kw + 1
-
-        # Filter-group outer loop + concatenate keeps the kernel scatter-free
-        # (``.at[].add`` lowers to gather/scatter constants Pallas rejects).
-        groups = []
-        for f0 in range(0, f_total, filter_group):
-            f1 = min(f0 + filter_group, f_total)
-            acc = jnp.zeros((bh, ho, wo, f1 - f0), jnp.float32)
-            for ky in range(kh):
-                for kx in range(kw):
-                    patch = x[:, ky : ky + ho, kx : kx + wo, :]
-                    wf = w[f0:f1, ky, kx, :]  # (fg, Cin)
-                    vid = vids[f0:f1, ky, kx]  # (fg,)
-                    prods = fp32_mul.fp32_multiply_interleaved(
-                        patch[..., None, :],  # (bh,ho,wo,1,Cin)
-                        wf[None, None, None, :, :],
-                        vid[None, None, None, :, None],
-                        scheme_stack=stack,
-                    )  # (bh,ho,wo,fg,Cin)
-                    acc = acc + jnp.sum(prods, axis=-1)
-            groups.append(acc)
-        o_ref[...] = groups[0] if len(groups) == 1 else jnp.concatenate(groups, axis=-1)
-
-    return _kernel
+LANES = 128
+ROW_BLOCK = 8  # one (8, 128) int32 vreg per plane tile
 
 
-def am_conv2d_bitexact_kernel(x, w, slot_map, *, batch_block=1,
-                              filter_group=FILTER_GROUP, interpret=True):
+def _kernel(w_ref, m_ref, x_ref, o_ref):
+    taps, cin = x_ref.shape[:2]
+    tile = x_ref.shape[2:]
+    zeros = jnp.zeros(tile, jnp.float32)
+    words = fp32_mul.N_MASK_WORDS
+
+    def per_filter(fi, carry):
+        def per_tap(t, acc):
+            slot = fi * taps + t
+            masks = [m_ref[slot * words + i] for i in range(words)]
+
+            def per_channel(ci, s):
+                b = jnp.full(tile, w_ref[slot * cin + ci], jnp.float32)
+                return s + fp32_mul.fp32_multiply_packed(x_ref[t, ci], b, masks)
+
+            return acc + jax.lax.fori_loop(0, cin, per_channel, zeros)
+
+        o_ref[fi] = jax.lax.fori_loop(0, taps, per_tap, zeros)
+        return carry
+
+    jax.lax.fori_loop(0, o_ref.shape[0], per_filter, 0)
+
+
+def am_conv2d_bitexact_kernel(x, w, slot_map, *, row_block=ROW_BLOCK):
     """x (B,H,W,Cin) f32, w (F,kh,kw,Cin) f32, slot_map (F,kh,kw) int32.
 
-    The scheme stack is fetched OUTSIDE the jit boundary and passed as an
-    operand: its (N_VARIANTS, 3, 48) shape keys the jit cache, so growing the
-    variant registry (repro.foundry) retraces instead of serving a stale
-    baked-in stack.
+    The code masks are fetched from the live registry OUTSIDE the jit
+    boundary, so growing the variant registry (repro.foundry) is seen on
+    the next call instead of serving a stale baked-in table.
     """
-    stack = jnp.asarray(schemes.scheme_stack(), jnp.int32)
-    return _am_conv2d_bitexact_jit(x, w, slot_map, stack,
-                                   batch_block=batch_block,
-                                   filter_group=filter_group,
-                                   interpret=interpret)
+    table = jnp.asarray(fp32_mul.mask_stack())
+    return _am_conv2d_bitexact_jit(x, w, jnp.asarray(slot_map, jnp.int32),
+                                   table, row_block=row_block,
+                                   interpret=platform.interpret_mode())
 
 
-@functools.partial(
-    jax.jit, static_argnames=("batch_block", "filter_group", "interpret")
-)
-def _am_conv2d_bitexact_jit(x, w, slot_map, stack, *, batch_block,
-                            filter_group, interpret):
+@functools.partial(jax.jit, static_argnames=("row_block", "interpret"))
+def _am_conv2d_bitexact_jit(x, w, slot_map, table, *, row_block, interpret):
     b, h, wd, cin = x.shape
     f, kh, kw, _ = w.shape
     ho, wo = h - kh + 1, wd - kw + 1
-    assert b % batch_block == 0
-
-    return pl.pallas_call(
-        _make_kernel(kh, kw, f, filter_group),
-        grid=(b // batch_block,),
+    taps, npos = kh * kw, b * ho * wo
+    planes = jnp.stack([x[:, ky:ky + ho, kx:kx + wo, :]
+                        for ky in range(kh) for kx in range(kw)])
+    planes = planes.transpose(0, 4, 1, 2, 3).reshape(taps, cin, npos)
+    tile = row_block * LANES
+    planes = jnp.pad(planes, ((0, 0), (0, 0), (0, (-npos) % tile)))
+    planes = planes.reshape(taps, cin, -1, LANES)
+    rows = planes.shape[2]
+    masks = table[slot_map.reshape(f * taps)].reshape(-1)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    out = pl.pallas_call(
+        _kernel,
+        grid=(rows // row_block,),
         in_specs=[
-            pl.BlockSpec((batch_block, h, wd, cin), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((f, kh, kw, cin), lambda i: (0, 0, 0, 0)),
-            pl.BlockSpec((f, kh, kw), lambda i: (0, 0, 0)),
-            pl.BlockSpec(stack.shape, lambda i: (0, 0, 0)),
+            smem,
+            smem,
+            pl.BlockSpec((taps, cin, row_block, LANES), lambda i: (0, 0, i, 0)),
         ],
-        out_specs=pl.BlockSpec((batch_block, ho, wo, f), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, ho, wo, f), jnp.float32),
+        out_specs=pl.BlockSpec((f, row_block, LANES), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((f, rows, LANES), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(x, w, jnp.asarray(slot_map, jnp.int32), stack)
+    )(w.reshape(-1), masks, planes)
+    out = out.reshape(f, -1)[:, :npos].reshape(f, b, ho, wo)
+    return out.transpose(1, 2, 3, 0)

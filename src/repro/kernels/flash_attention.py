@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import platform
+
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_KV = 512
 NEG_INF = -1e30
@@ -77,17 +79,24 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         o_ref[...] = o_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("causal", "window", "chunked", "block_q", "block_kv",
-                     "interpret"))
 def flash_attention_kernel(q, k, v, *, causal=True, window=0, chunked=False,
-                           block_q=DEFAULT_BLOCK_Q, block_kv=DEFAULT_BLOCK_KV,
-                           interpret=True):
+                           block_q=DEFAULT_BLOCK_Q, block_kv=DEFAULT_BLOCK_KV):
     """q: (B, Sq, H, Dh); k, v: (B, Skv, KV, Dh), H % KV == 0.
 
     Returns (B, Sq, H, Dh) in q.dtype. Sq % block_q == Skv % block_kv == 0.
     """
+    return _flash_attention_jit(q, k, v, causal=causal, window=window,
+                                chunked=chunked, block_q=block_q,
+                                block_kv=block_kv,
+                                interpret=platform.interpret_mode())
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("causal", "window", "chunked", "block_q", "block_kv",
+                     "interpret"))
+def _flash_attention_jit(q, k, v, *, causal, window, chunked, block_q,
+                         block_kv, interpret):
     b, sq, h, dh = q.shape
     skv, kvh = k.shape[1], k.shape[2]
     rep = h // kvh

@@ -3,18 +3,20 @@ the roofline-autotuned block chooser shared by every Pallas entry point.
 
 These are the kernel-level primitives the AM engine (core/engine.py)
 dispatches to; call them directly only when you need explicit control over
-blocks or the interpret flag. `impl="kernel"` runs the Pallas kernel
-(interpret=True off TPU, compiled on TPU); `impl="ref"` runs the pure-jnp
-oracle; the surrogate path adds `impl="fused_xla"` — the same fused one-pass
-contraction expressed as a single XLA computation, the fast spelling on this
-CPU build box — and `impl="auto"` (kernel on TPU, fused_xla otherwise).
+blocks. `impl="kernel"` runs the Pallas kernel (compiled on a TPU, in
+interpret mode elsewhere — kernels/platform.py decides per call);
+`impl="ref"` runs the pure-jnp oracle; the surrogate path adds
+`impl="fused_xla"` — the same fused one-pass contraction expressed as a
+single XLA computation, the fast spelling on a CPU host — and
+`impl="auto"` (kernel on TPU, fused_xla otherwise).
 Shapes are padded to block multiples and cropped back.
 
 Block selection (`choose_block`) is an autotuner: candidate block shapes that
 fit the VMEM budget are scored against the kernel roofline model
 (roofline/analysis.py::surrogate_block_time / bitexact_block_time) for the
-current target — TPU v5e on TPU, the 2-core ~1.2 GB/s build box otherwise —
-and the winner is memoized in a per-shape tuning cache persisted to
+current target — the TPU's entry in the peak table keyed by device_kind
+(an unlisted TPU raises), the 2-core ~1.2 GB/s build box off TPU — and the
+winner is memoized in a per-shape tuning cache persisted to
 artifacts/tuning_cache.json (override with $REPRO_TUNING_CACHE). Given a
 cache entry the chooser is a pure lookup, so block choices are deterministic
 across runs and across model revisions until the cache is regenerated.
@@ -29,29 +31,31 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import fp32_mul
 from repro.kernels import am_surrogate_matmul as _sgk
 from repro.kernels import approx_conv as _convk
 from repro.kernels import approx_matmul as _mmk
 from repro.kernels import bitexact_emulator as _emuk
+from repro.kernels import platform
 from repro.kernels import ref as _ref
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 # Per-core VMEM envelopes the chooser sizes against (TPU v5e has ~16 MiB per
 # core; the bit-exact kernels leave headroom for the compiler's own buffers).
 VMEM_BYTES = 16 * 2**20
-BITEXACT_VMEM_BUDGET = 4 * 2**20
+BITEXACT_VMEM_BUDGET = 8 * 2**20
 
-# Bit-exact emulation's dominant temporary is the partial-product bit tensor:
-# (..., 10 rows, 48 cols) int32 per emulated multiply = 1920 B per element of
-# the block. The fused surrogate kernel's live set is x (bm,bk) + folded
-# wm/wv (bk,bn)*2 + z/out/var (bm,bn)*3 f32 (the unfolded moments kernel's
-# w/mu/sg + two accumulators is the same size).
-_PPM_BYTES_PER_MUL = 10 * 48 * 4
+# The bit-exact matmul kernel double-buffers its x (bm,bk), w (bk,bn) and
+# 24-word mask (24,bk,bn) blocks, keeps 4 lane accumulators and the output
+# (bm,bn) tile, and the packed emulation of one k row holds about
+# _EMU_TILE_WORDS live (bm,bn) int32 tiles. The fused surrogate kernel's
+# live set is x (bm,bk) + folded wm/wv (bk,bn)*2 + z/out/var (bm,bn)*3 f32
+# (the unfolded moments kernel's w/mu/sg + two accumulators is the same size).
+_EMU_TILE_WORDS = 64
 
 
 def _bitexact_live_bytes(bm: int, bk: int, bn: int) -> int:
-    return bm * bk * bn * _PPM_BYTES_PER_MUL
+    buffered = bm * bk + (1 + fp32_mul.N_MASK_WORDS) * bk * bn
+    return 4 * (2 * buffered + (4 + 2 + _EMU_TILE_WORDS) * bm * bn)
 
 
 def _surrogate_live_bytes(bm: int, bk: int, bn: int) -> int:
@@ -65,7 +69,7 @@ def _surrogate_live_bytes(bm: int, bk: int, bn: int) -> int:
 TUNING_CACHE_ENV = "REPRO_TUNING_CACHE"
 # Bump when candidate enumeration or the scoring model changes shape: stale
 # cache entries for old versions are ignored rather than misapplied.
-_TUNE_VERSION = 1
+_TUNE_VERSION = 2
 
 _tuning_cache: dict[str, list] = {}
 _disk_cache_loaded = False
@@ -118,7 +122,9 @@ def clear_tuning_cache() -> None:
 def _kernel_target():
     from repro.roofline import analysis
 
-    return analysis.TPU_V5E_KERNEL if _ON_TPU else analysis.BUILD_BOX_KERNEL
+    if not platform.on_tpu():
+        return analysis.BUILD_BOX_KERNEL
+    return analysis.kernel_target(platform.device_kind())
 
 
 def _pow2_ceil(x: int) -> int:
@@ -135,37 +141,38 @@ def _pow2_range(lo: int, hi: int) -> list[int]:
 
 def candidate_blocks(kind: str, m: int, k: int, n: int,
                      *, vmem_bytes: int | None = None) -> list[tuple]:
-    """Power-of-two (bm, bk, bn) candidates that fit the VMEM budget.
+    """Tile-legal (bm, bk, bn) candidates that fit the VMEM budget.
 
-    Dims are capped at the pow2 ceiling of the problem (no block larger than
-    the padded problem) and at the kernel's practical maxima; every returned
-    candidate satisfies the kind's live-set budget, so any of them is safe
-    to launch — the scorer only decides which is fastest.
+    Legal for Mosaic: bm is a multiple of 8, and bk and bn are multiples of
+    128 or the whole padded dim (the wrappers pad each dim to a multiple of
+    its block). Power-of-two dims are capped at the pow2 ceiling of the
+    problem and at the kernel's practical maxima; every returned candidate
+    satisfies the kind's live-set budget, so any of them is safe to launch —
+    the scorer only decides which is fastest.
     """
     if kind == "bitexact_matmul":
         budget = vmem_bytes or BITEXACT_VMEM_BUDGET
         fits = _bitexact_live_bytes
-        caps = (min(_pow2_ceil(m), 32), min(_pow2_ceil(k), 64),
-                min(_pow2_ceil(n), 64))
-        lo = 1
+        bms = _pow2_range(8, min(max(_pow2_ceil(m), 8), 32))
+        bns = _pow2_range(128, min(max(_pow2_ceil(n), 128), 256))
+        # K up to 128 is one whole block (padded to the 8-row w tile).
+        bks = ([-(-k // 8) * 8] if k <= 128
+               else _pow2_range(128, min(_pow2_ceil(k), 512)))
     elif kind == "surrogate_matmul":
         budget = vmem_bytes or VMEM_BYTES
         fits = _surrogate_live_bytes
-        caps = (max(_pow2_ceil(m), 8), max(_pow2_ceil(k), 8),
-                max(_pow2_ceil(n), 8))
-        caps = tuple(min(c, 512) for c in caps)
-        lo = 8
+        caps = tuple(min(max(_pow2_ceil(d), 8), 512) for d in (m, k, n))
+        bms = _pow2_range(8, caps[0])
+        bks = [b for b in _pow2_range(8, caps[1])
+               if b % 128 == 0 or b == caps[1]]
+        bns = [b for b in _pow2_range(8, caps[2])
+               if b % 128 == 0 or b == caps[2]]
     else:
         raise ValueError(f"no block candidates for kind {kind!r}")
-    cands = [
-        (bm, bk, bn)
-        for bm in _pow2_range(min(lo, caps[0]), caps[0])
-        for bk in _pow2_range(min(lo, caps[1]), caps[1])
-        for bn in _pow2_range(min(lo, caps[2]), caps[2])
-        if fits(bm, bk, bn) <= budget
-    ]
-    if not cands:  # degenerate budget: smallest legal block, clipped
-        cands = [(min(lo, caps[0]), min(lo, caps[1]), min(lo, caps[2]))]
+    cands = [(bm, bk, bn) for bm in bms for bk in bks for bn in bns
+             if fits(bm, bk, bn) <= budget]
+    if not cands:  # degenerate budget: smallest legal block
+        cands = [(bms[0], bks[0], bns[0])]
     return cands
 
 
@@ -176,7 +183,7 @@ def score_block(kind: str, block, m: int, k: int, n: int) -> float:
     target = _kernel_target()
     if kind == "bitexact_matmul":
         return analysis.bitexact_block_time(
-            m, k, n, block, target, ppm_bytes_per_mul=_PPM_BYTES_PER_MUL)
+            m, k, n, block, target, mask_words=fp32_mul.N_MASK_WORDS)
     if kind == "surrogate_matmul":
         return analysis.surrogate_block_time(m, k, n, block, target)
     raise ValueError(f"no block model for kind {kind!r}")
@@ -200,28 +207,17 @@ def autotune_block(kind: str, m: int, k: int, n: int,
 def choose_block(kind: str, m: int, k: int, n: int, *, vmem_bytes: int | None = None):
     """One block chooser for all Pallas entry points (autotuned + cached).
 
-    kind="bitexact_matmul": (bm, bk, bn) whose PPM bit tensor
-      bm*bk*bn * 1920 B fits the bit-exact VMEM budget (default 4 MiB).
+    kind="bitexact_matmul": (bm, bk, bn) whose kernel live set
+      (_bitexact_live_bytes) fits the bit-exact VMEM budget (default 8 MiB).
     kind="surrogate_matmul": (bm, bk, bn) whose fused-kernel live set
       (bm*bk + 3*bk*bn + 3*bm*bn) * 4 B fits the v5e VMEM envelope.
-    kind="bitexact_conv": the filter-group size FG limiting the per-tap bit
-      tensor ho*wo*cin*FG * 1920 B (m=ho*wo, k=cin, n=F here) — analytic,
-      a scalar maximization, not worth a tuning-cache entry.
 
-    Matmul kinds consult the tuning cache first (in-memory, seeded from
+    Both kinds consult the tuning cache first (in-memory, seeded from
     artifacts/tuning_cache.json / $REPRO_TUNING_CACHE); on a miss the
     roofline autotuner runs and the result is recorded and best-effort
     persisted, so later runs — and CI, which checks the cache in — are pure
     lookups.
     """
-    if kind == "bitexact_conv":
-        # The per-tap bit tensor streams through the pipeline in stages, so
-        # the live set is a fraction of the full (m*k*FG) PPM tensor; the
-        # default budget recovers the hand-derived FG=4 on the paper CNN
-        # (ho*wo=900, cin=3, F=12).
-        budget = vmem_bytes or (20 * 2**20)
-        per_filter = max(m * k, 1) * _PPM_BYTES_PER_MUL
-        return max(1, min(n, budget // per_filter))
     if kind not in ("bitexact_matmul", "surrogate_matmul"):
         raise ValueError(f"unknown block kind {kind!r}")
     key = (f"v{_TUNE_VERSION}:{_kernel_target().name}:{kind}:"
@@ -257,13 +253,13 @@ def _pad_to(x, mults, axes):
 def am_surrogate_moments(x, w, mu, sg, *, block=None, impl="auto"):
     """Fused statistical AM matmul moments: (mean, var), both (M, N) f32.
 
-    impl: "kernel" (Pallas, interpret off TPU) | "fused_xla" (one jitted XLA
+    impl: "kernel" (Pallas) | "fused_xla" (one jitted XLA
     computation, bit-identical to the oracle) | "ref" | "auto".
     """
     m, k = x.shape
     n = w.shape[1]
     if impl == "auto":
-        impl = "kernel" if _ON_TPU else "fused_xla"
+        impl = "kernel" if platform.on_tpu() else "fused_xla"
     if impl == "ref" or impl == "fused_xla":
         return _fused_xla_moments(x, w, mu, sg)
     block = block or choose_block("surrogate_matmul", m, k, n)
@@ -273,8 +269,7 @@ def am_surrogate_moments(x, w, mu, sg, *, block=None, impl="auto"):
     mup = _pad_to(mu, (bk, bn), (0, 1))
     sgp = _pad_to(sg, (bk, bn), (0, 1))
     mean, var = _sgk.am_surrogate_matmul_kernel(
-        xp, wp, mup, sgp, block=(bm, bk, bn), interpret=not _ON_TPU
-    )
+        xp, wp, mup, sgp, block=(bm, bk, bn))
     return mean[:m, :n], var[:m, :n]
 
 
@@ -305,7 +300,7 @@ def am_surrogate_moments_folded(x, w_mean, w_var, *, block=None, impl="auto"):
     m, k = x.shape
     n = w_mean.shape[-1]
     if impl == "auto":
-        impl = "kernel" if _ON_TPU else "fused_xla"
+        impl = "kernel" if platform.on_tpu() else "fused_xla"
     if impl in ("ref", "fused_xla"):
         return _stacked_moments(x, w_mean, w_var)
     block = block or choose_block("surrogate_matmul", m, k, n)
@@ -314,8 +309,7 @@ def am_surrogate_moments_folded(x, w_mean, w_var, *, block=None, impl="auto"):
     wmp = _pad_to(w_mean, (bk, bn), (0, 1))
     wvp = _pad_to(w_var, (bk, bn), (0, 1))
     mean, var = _sgk.am_surrogate_matmul_folded_kernel(
-        xp, wmp, wvp, block=(bm, bk, bn), interpret=not _ON_TPU
-    )
+        xp, wmp, wvp, block=(bm, bk, bn))
     return mean[:m, :n], var[:m, :n]
 
 
@@ -338,7 +332,7 @@ def am_surrogate_matmul_epilogue(x, w_mean, w_var, z, *, block=None,
     pop = w_mean.ndim == 3
     pop_x = x.ndim == 3
     if impl == "auto":
-        impl = "kernel" if _ON_TPU else "fused_xla"
+        impl = "kernel" if platform.on_tpu() else "fused_xla"
     if impl in ("ref", "fused_xla"):
         xf = x.astype(jnp.float32)
         if not pop:
@@ -361,8 +355,7 @@ def am_surrogate_matmul_epilogue(x, w_mean, w_var, z, *, block=None,
     wvp = _pad_to(w_var, (bk, bn), (w_var.ndim - 2, w_var.ndim - 1))
     zp = _pad_to(z, (bm, bn), (0, 1))
     out = _sgk.am_surrogate_matmul_epilogue_kernel(
-        xp, wmp, wvp, zp, block=(bm, bk, bn), interpret=not _ON_TPU
-    )
+        xp, wmp, wvp, zp, block=(bm, bk, bn))
     return out[..., :m, :n]
 
 
@@ -382,35 +375,32 @@ def am_surrogate_matmul(x, w, mu, sg, key, *, block=None, impl="kernel"):
 
 
 def am_matmul_bitexact(x, w, variant_ids, *, block=None, impl="kernel"):
-    """Bit-exact interleaved AM matmul."""
+    """Bit-exact interleaved AM matmul.
+
+    The per-slot variant ids become per-slot column-mask words (one XLA
+    gather from the live registry's mask table) before the kernel runs.
+    Dims pad to block multiples with zeros (exact slots), which leaves the
+    defined accumulation order's sums unchanged, and crop back.
+    """
     if impl == "ref":
         return _ref.am_matmul_bitexact_ref(x, w, variant_ids)
     m, k = x.shape
     n = w.shape[1]
-    block = block or choose_block("bitexact_matmul", m, k, n)
-    bm, bk, bn = block
-    xp = _pad_to(x, (bm, bk), (0, 1))
-    wp = _pad_to(w, (bk, bn), (0, 1))
-    vp = _pad_to(jnp.asarray(variant_ids, jnp.int32), (bk, bn), (0, 1))
-    out = _mmk.am_matmul_bitexact_kernel(
-        xp, wp, vp, block=(bm, bk, bn), interpret=not _ON_TPU
-    )
+    bm, bk, bn = block or choose_block("bitexact_matmul", m, k, n)
+    vids = jnp.asarray(variant_ids, jnp.int32)
+    masks = jnp.moveaxis(jnp.asarray(fp32_mul.mask_stack())[vids], -1, 0)
+    xp = _pad_to(x.astype(jnp.float32), (bm, bk), (0, 1))
+    wp = _pad_to(w.astype(jnp.float32), (bk, bn), (0, 1))
+    mp = _pad_to(masks, (bk, bn), (1, 2))
+    out = _mmk.am_matmul_bitexact_kernel(xp, wp, mp, block=(bm, bk, bn))
     return out[:m, :n]
 
 
-def am_conv2d_bitexact(x, w, slot_map, *, impl="kernel", batch_block=1,
-                       filter_group=None):
+def am_conv2d_bitexact(x, w, slot_map, *, impl="kernel"):
     """Bit-exact interleaved conv2d (NHWC, VALID, stride 1)."""
     if impl == "ref":
         return _ref.am_conv2d_bitexact_ref(x, w, slot_map)
-    b, h, wd, cin = x.shape
-    f, kh, kw, _ = w.shape
-    ho, wo = h - kh + 1, wd - kw + 1
-    fg = filter_group or choose_block("bitexact_conv", ho * wo, cin, f)
-    return _convk.am_conv2d_bitexact_kernel(
-        x, w, slot_map, batch_block=batch_block, filter_group=fg,
-        interpret=not _ON_TPU,
-    )
+    return _convk.am_conv2d_bitexact_kernel(x, w, slot_map)
 
 
 def fp32_multiply_stacked(a, b, scheme_maps, *, chunk: int | None = None,
@@ -426,8 +416,8 @@ def fp32_multiply_stacked(a, b, scheme_maps, *, chunk: int | None = None,
     a, b: float32 (n,) host or device arrays; scheme_maps: (V, 3, 48) int32.
     impl: "fused_xla" (one jitted broadcast emulation per chunk — the build
     box spelling, bit-identical to per-variant fp32_multiply_batch) |
-    "kernel" (Pallas grid over (variant block, operand chunk), interpret off
-    TPU) | "auto". Returns np.float32 (V, n).
+    "kernel" (Pallas grid over (variant block, operand chunk)) | "auto"
+    (kernel on a TPU). Returns np.float32 (V, n).
     """
     a = np.asarray(a, np.float32).ravel()
     b = np.asarray(b, np.float32).ravel()
@@ -435,16 +425,13 @@ def fp32_multiply_stacked(a, b, scheme_maps, *, chunk: int | None = None,
     if maps.ndim != 3 or maps.shape[1:] != (3, 48):
         raise ValueError(f"scheme_maps must be (V, 3, 48), got {maps.shape}")
     if impl == "auto":
-        impl = "kernel" if _ON_TPU else "fused_xla"
+        impl = "kernel" if platform.on_tpu() else "fused_xla"
     if chunk is None:
         chunk = max(1 << 10, (1 << 15) // max(maps.shape[0], 1))
     if impl == "kernel":
-        return _emuk.fp32_multiply_stacked_kernel(
-            a, b, maps, chunk=chunk, interpret=not _ON_TPU)
+        return _emuk.fp32_multiply_stacked_kernel(a, b, maps, chunk=chunk)
     if impl != "fused_xla":
         raise ValueError(f"unknown impl {impl!r}")
-    from repro.core import fp32_mul
-
     codes = jnp.asarray(maps)[:, None]  # (V, 1, 3, 48)
     outs = []
     for i in range(0, a.size, chunk):

@@ -2,8 +2,9 @@
 
 These are the ground truth the kernels must match (tests sweep shapes/dtypes
 and assert_allclose / bit-equality). They are also the implementations used by
-the heavy paper experiments (jit-compiled, vectorized) — the Pallas kernels
-target TPU and are validated here in interpret mode.
+the heavy paper experiments (jit-compiled, vectorized). The bit-exact oracles
+fix their summation order explicitly, and the Pallas kernels share it, so
+kernel and oracle agree bit for bit on any backend.
 """
 from __future__ import annotations
 
@@ -27,33 +28,50 @@ def am_surrogate_matmul_ref(x, w, mu, sigma):
     return mean, var
 
 
-def am_matmul_bitexact_ref(x, w, variant_ids, chunk_m: int = 8, chunk_k: int | None = None):
+# The matmul's defined k order: lane j sums the products of k = j, j+4, ...
+# sequentially from +0.0, and the lanes combine as (l0 + l2) + (l1 + l3).
+# Of the 945 summation trees over the golden fixture's K = 6 products, this
+# is the only one that reproduces all ten committed matmul goldens; the
+# Pallas kernel (kernels/approx_matmul.py) accumulates in the same order.
+N_LANES = 4
+
+
+def combine_lanes(lanes):
+    """The defined combination of the N_LANES lane sums."""
+    return (lanes[0] + lanes[2]) + (lanes[1] + lanes[3])
+
+
+def lane_sum(prods):
+    """Sum (M, K, N) products over K in the defined k order -> (M, N)."""
+    m, k, n = prods.shape
+    p = jnp.pad(prods, ((0, 0), (0, (-k) % N_LANES), (0, 0)))
+    p = p.reshape(m, -1, N_LANES, n).transpose(1, 2, 0, 3)  # (K/4, 4, M, N)
+    lanes, _ = jax.lax.scan(lambda acc, pk: (acc + pk, None),
+                            jnp.zeros((N_LANES, m, n), jnp.float32), p)
+    return combine_lanes(lanes)
+
+
+def am_matmul_bitexact_ref(x, w, variant_ids, chunk_m: int = 8):
     """Bit-exact AM matmul oracle.
 
     x: (M, K) f32; w: (K, N) f32; variant_ids: (K, N) int32 per-slot variants.
     Every scalar product uses the slot's multiplier; accumulation is exact f32
-    (the paper approximates multipliers only; adders stay exact).
-
-    ``chunk_k`` reproduces the Pallas kernel's blocked-k accumulation order
-    (sum within each k block, then add blocks sequentially), so kernel-vs-ref
-    comparisons are bit-identical rather than merely allclose.
+    (the paper approximates multipliers only; adders stay exact), in the
+    defined k order (lane_sum). Rows go through in chunks of ``chunk_m``:
+    the bit tensor is 1920 B per multiply.
     """
     m, k = x.shape
     n = w.shape[1]
     vids = jnp.asarray(variant_ids, jnp.int32)
-    ck = chunk_k or k
 
     def block(xb):
-        acc = jnp.zeros((xb.shape[0], n), jnp.float32)
-        for k0 in range(0, k, ck):
-            k1 = min(k0 + ck, k)
-            prods = fp32_mul.fp32_multiply_interleaved(
-                jnp.broadcast_to(xb[:, k0:k1, None], (xb.shape[0], k1 - k0, n)),
-                jnp.broadcast_to(w[None, k0:k1, :], (xb.shape[0], k1 - k0, n)),
-                vids[None, k0:k1, :],
-            )
-            acc = acc + jnp.sum(prods, axis=1)
-        return acc
+        mb = xb.shape[0]
+        prods = fp32_mul.fp32_multiply_interleaved(
+            jnp.broadcast_to(xb[:, :, None], (mb, k, n)),
+            jnp.broadcast_to(w[None, :, :], (mb, k, n)),
+            vids[None, :, :],
+        )
+        return lane_sum(prods)
 
     outs = [block(x[i : i + chunk_m]) for i in range(0, m, chunk_m)]
     return jnp.concatenate(outs, axis=0)
@@ -66,6 +84,10 @@ def am_conv2d_bitexact_ref(x, w, slot_map):
     slot_map: (F, kh, kw) int32 — the paper's per-(filter, coefficient)
     multiplier assignment, shared across input channels.
     Returns (B, H-kh+1, W-kw+1, F) f32.
+
+    Defined order (shared with kernels/approx_conv.py): taps in (ky, kx)
+    order, each adding the sequential left fold of its channel products
+    from +0.0.
     """
     b, h, wd, cin = x.shape
     f, kh, kw, _ = w.shape
@@ -83,7 +105,10 @@ def am_conv2d_bitexact_ref(x, w, slot_map):
                 wf[None, None, None, :, :],  # (1,1,1,F,Cin)
                 vid[None, None, None, :, None],
             )  # (B,ho,wo,F,Cin)
-            acc = acc + jnp.sum(prods, axis=-1)
+            tap = jnp.zeros((b, ho, wo, f), jnp.float32)
+            for c in range(cin):
+                tap = tap + prods[..., c]
+            acc = acc + tap
     return acc
 
 

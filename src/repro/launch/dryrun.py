@@ -37,7 +37,6 @@ def run_cell(arch: str, shape: str, mesh_name: str, verbose: bool = True) -> dic
     import jax
 
     from repro.launch import mesh as meshlib
-    from repro.parallel import sharding as shd
     from repro.launch import steps
     from repro.models import registry as R
     from repro.optim import adamw
@@ -51,7 +50,7 @@ def run_cell(arch: str, shape: str, mesh_name: str, verbose: bool = True) -> dic
     kind = sh["kind"]
 
     t0 = time.time()
-    with shd.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             opt_cfg = adamw.AdamWConfig()
             # NOTE (§Perf iteration 5, REFUTED): passing param_specs here to

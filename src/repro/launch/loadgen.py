@@ -28,7 +28,7 @@ import time
 
 import numpy as np
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.launch import mesh as meshlib
 from repro.launch.serve import DEFAULT_TIER_POLICIES, Request, Server
 from repro.models import registry as R
@@ -275,6 +275,7 @@ def main() -> None:
                          "always runs traced)")
     ap.add_argument("--no-obs", dest="obs", action="store_false")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.obs is not None:
         obs.set_enabled(args.obs)
     res = bench(arch=args.arch, requests=args.requests, max_new=args.max_new,

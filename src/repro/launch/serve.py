@@ -45,13 +45,13 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.core import amlinear, engine
 from repro.launch import mesh as meshlib
 from repro.models import registry as R
 from repro.obs import watchdog
-from repro.parallel import sharding as shd
 
 # The shipped tier menu: accuracy-ranked alphabet positions (interleave.py)
 # ground the conservative/aggressive split — conservative is the paper's
@@ -144,7 +144,11 @@ class Server:
         self.mode = mode
         self.prefill_chunk = max(1, int(prefill_chunk))
         self.params = R.init_params(cfg, jax.random.PRNGKey(seed))
-        self.cache = R.init_cache(cfg, slots, ctx)
+        # Committed to the mesh from the start, as the step's outputs are, so
+        # the first reset sees the same input shardings as every later one
+        # (one trace, not two).
+        self.cache = jax.device_put(R.init_cache(cfg, slots, ctx),
+                                    NamedSharding(mesh, PartitionSpec()))
         # Pristine per-slot state for slot recycling. Distinct device buffers
         # (the live cache is donated to the jitted step/reset calls).
         self._fresh = jax.tree.map(jnp.copy, self.cache)
@@ -298,7 +302,7 @@ class Server:
         if fresh:
             mask = np.zeros(self.slots, bool)
             mask[fresh] = True
-            with shd.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 self.cache = self._jit_reset(self.cache, self._fresh,
                                              jnp.asarray(mask))
 
@@ -307,7 +311,7 @@ class Server:
     def _invoke(self, tokens: np.ndarray, lens: np.ndarray) -> np.ndarray:
         with obs.span("serve.dispatch", mode=self.mode,
                       rows=int((lens > 0).sum()), chunk=int(tokens.shape[1])), \
-                shd.set_mesh(self.mesh):
+                jax.set_mesh(self.mesh):
             nxt, self.cache = self._jit_step(
                 self.params, self.cache, jnp.asarray(tokens),
                 jnp.asarray(self.pos), jnp.asarray(lens),
@@ -483,7 +487,7 @@ class Server:
         if self._jit_audit_tier is None:
             self._jit_audit_tier = self._build_audit_step(exact=False)
             self._jit_audit_exact = self._build_audit_step(exact=True)
-        with shd.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             # self._fresh is never donated or mutated here: both replays
             # start from the pristine cache a fresh admission would get.
             lg_t = np.asarray(self._jit_audit_tier(
@@ -609,6 +613,7 @@ def main() -> None:
                     help="shadow-exact audit fraction of finished requests "
                          "(implies --obs; 0 disables)")
     args = ap.parse_args()
+    compile_cache.enable()
     if (args.trace_out is not None or args.audit_fraction > 0) \
             and args.obs is None:
         args.obs = True

@@ -32,7 +32,6 @@ from repro.launch import mesh as meshlib
 from repro.launch import steps as steplib
 from repro.models import registry as R
 from repro.optim import adamw, grad_compress
-from repro.parallel import sharding as shd
 
 
 @dataclasses.dataclass
@@ -99,7 +98,7 @@ class TrainRun:
 
     def _loop(self, params, opt_state, ebuf, start, steps, log_every,
               abort_at, history):
-        with shd.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             for step in range(start, start + steps):
                 if abort_at is not None and step >= abort_at:
                     raise RuntimeError(f"simulated node failure at step {step}")

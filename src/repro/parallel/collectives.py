@@ -20,12 +20,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.parallel import sharding as shd
-
 
 def psum_scatter_grads(grads, axis: str, *, tiled: bool = True):
     """Reduce-scatter every leaf over `axis` along its largest divisible dim."""
-    n = shd.axis_size(axis)
+    n = jax.lax.axis_size(axis)
 
     def leaf(g):
         for d, size in enumerate(g.shape):
@@ -44,7 +42,7 @@ def ring_allgather(x, axis: str):
     this device's own shard (matches lax.all_gather(..., tiled=False) up to
     known rotation; tests compare against the roll).
     """
-    n = shd.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def step(carry, _):
@@ -63,7 +61,7 @@ def overlapped_matmul_allgather(x_shard, w, axis: str):
     that just arrived while the next hop is in flight — XLA overlaps the
     ppermute with the dot because there is no data dependence.
     """
-    n = shd.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     perm = [(i, (i + 1) % n) for i in range(n)]
     m = x_shard.shape[0]

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.parallel import sharding as shd
-
 
 def pipelined_apply(stage_fn, stage_params, x, *, mesh: Mesh, axis: str = "stage"):
     """Run S pipeline stages over M microbatches.
@@ -63,9 +61,9 @@ def pipelined_apply(stage_fn, stage_params, x, *, mesh: Mesh, axis: str = "stage
 
         # carries become device-varying after the first ppermute: mark the
         # initial values as varying so the scan carry type is stable.
-        buf0 = shd.pcast(jnp.zeros(mb_shape, xs.dtype), (axis,),
+        buf0 = jax.lax.pcast(jnp.zeros(mb_shape, xs.dtype), (axis,),
                          to="varying")
-        outs0 = shd.pcast(jnp.zeros((m,) + mb_shape, xs.dtype), (axis,),
+        outs0 = jax.lax.pcast(jnp.zeros((m,) + mb_shape, xs.dtype), (axis,),
                           to="varying")
         (_, outs), _ = jax.lax.scan(tick, (buf0, outs0), jnp.arange(total))
         # outs is valid only on the last stage; psum of masked copies
@@ -75,7 +73,7 @@ def pipelined_apply(stage_fn, stage_params, x, *, mesh: Mesh, axis: str = "stage
         return outs
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
-    return shd.shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(pspec, P()), out_specs=P(),
     )(stage_params, x)

@@ -25,64 +25,10 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# ---------------------------------------------------------------------------
-# jax version compatibility
-#
-# The repo targets current jax (AxisType meshes, jax.set_mesh, jax.shard_map,
-# jax.lax.pcast); CI and the build box may run an older release where those
-# live under different names or don't exist. Every call site routes through
-# these shims so the rest of the codebase can use one spelling.
-# ---------------------------------------------------------------------------
-
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
-
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where the kwarg exists."""
-    if _HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
-
-
-def set_mesh(mesh: Mesh):
-    """Context manager installing `mesh`: jax.set_mesh, or the legacy
-    `with mesh:` protocol (Mesh is itself a context manager there)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """jax.shard_map / jax.experimental.shard_map with kwarg renames
-    (`check_vma` was `check_rep` before the varying-manual-axes rework)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental import shard_map as _sm
-
-    if "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _sm.shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
-
-
-def pcast(x, axes, *, to=None):
-    """jax.lax.pcast where it exists; identity otherwise (legacy shard_map
-    with check_rep=False does not track varying axes, so no cast is needed)."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to=to)
-    return x
-
-
-def axis_size(axis: str) -> int:
-    """jax.lax.axis_size, or the psum(1) spelling on older jax."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
+    """jax.make_mesh with every axis Auto (GSPMD-propagated sharding)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_pop_mesh(n_devices: int | None = None, axis_name: str = "pop") -> Mesh:

@@ -16,8 +16,8 @@ MODEL_FLOPS = 6·N_active·tokens (train) / 2·N_active·tokens (serve) is the
 useful-work yardstick; useful_flops_frac = MODEL_FLOPS / (HLO_FLOPs · chips)
 exposes remat recompute and attention/dispatch overheads.
 
-Hardware constants (assignment): TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware constants: the per-chip peak table DEVICE_PEAKS, keyed by JAX's
+``device_kind``, with its source.
 """
 from __future__ import annotations
 
@@ -27,9 +27,41 @@ import numpy as np
 
 from repro.roofline import hlo_costs
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # B/s / chip
-ICI_BW = 50e9  # B/s / link
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeak:
+    """Published peaks of one chip."""
+
+    name: str
+    bf16_flops: float  # FLOP/s
+    int8_ops: float  # OP/s
+    hbm_bytes: float  # B
+    hbm_bw: float  # B/s
+    ici_bw: float  # B/s per link
+
+
+# Keyed by jax.devices()[i].device_kind. Source: Google Cloud documentation,
+# "TPU v5e" — 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of interconnect per chip (4 links of 50 GB/s).
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeak("tpu_v5e", 197e12, 393e12, 16e9, 819e9, 50e9),
+}
+
+
+def device_peak(device_kind: str) -> DevicePeak:
+    """The peak table's entry for ``device_kind``; an unlisted kind raises."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add it to "
+            "roofline/analysis.py DEVICE_PEAKS with its source") from None
+
+
+_V5E = DEVICE_PEAKS["TPU v5 lite"]
+PEAK_FLOPS = _V5E.bf16_flops  # the dry-run analyses' target chip
+HBM_BW = _V5E.hbm_bw
+ICI_BW = _V5E.ici_bw
 
 
 # ---------------------------------------------------------------------------
@@ -62,8 +94,12 @@ class KernelTarget:
     launch_overhead: float  # seconds per grid program
 
 
-# TPU v5e per-core (assignment constants above; MXU is 128x128).
-TPU_V5E_KERNEL = KernelTarget("tpu_v5e", PEAK_FLOPS, HBM_BW, 128, 1e-6)
+def kernel_target(device_kind: str) -> KernelTarget:
+    """Scoring target for a TPU chip from the peak table (MXU is 128x128)."""
+    peak = device_peak(device_kind)
+    return KernelTarget(peak.name, peak.bf16_flops, peak.hbm_bw, 128, 1e-6)
+
+
 # The 2-core ~1.2 GB/s build box: 2 cores x ~3 GHz x 8-lane FMA, with
 # cache-resident blocking the goal (hence the small align and the large
 # relative dispatch overhead of interpret-mode/XLA loop bodies).
@@ -108,22 +144,20 @@ def surrogate_block_time(m: int, k: int, n: int, block, target: KernelTarget,
 
 
 def bitexact_block_time(m: int, k: int, n: int, block, target: KernelTarget,
-                        *, ppm_bytes_per_mul: int = 1920) -> float:
+                        *, mask_words: int = 24) -> float:
     """Modeled seconds for the bit-exact emulation kernel.
 
-    Dominated by the partial-product bit tensor (ppm_bytes_per_mul per
-    emulated multiply) streaming through the memory system, with the same
-    tile-traffic and per-program terms as the surrogate model; the ~600
-    int-ops per multiply ride the same ppm term (they are proportional).
+    Compute: ~600 int32 ops per emulated multiply on (bm, bn) tiles. Memory:
+    per program an x tile (bm, bk), a w tile and ``mask_words`` column-mask
+    words per (k, n) slot; the same per-program term as the surrogate model.
     """
     bm, bk, bn = block
     gm, gk, gn = _ceil_div(m, bm), _ceil_div(k, bk), _ceil_div(n, bn)
     programs = gm * gn * gk
     muls = float(programs) * bm * bk * bn
-    ppm_bytes = muls * ppm_bytes_per_mul
     x_bytes = 4.0 * programs * bm * bk
-    w_bytes = 4.0 * programs * 2 * bk * bn  # w + variant ids
-    t_memory = (ppm_bytes + x_bytes + w_bytes) / target.mem_bw
+    w_bytes = 4.0 * programs * (1 + mask_words) * bk * bn
+    t_memory = (x_bytes + w_bytes) / target.mem_bw
     t_compute = 600.0 * muls / (target.peak_flops
                                 * _mxu_utilization(bm, bn, bk, target.align))
     return t_compute + t_memory + programs * target.launch_overhead

@@ -120,20 +120,20 @@ def test_ring_allgather_and_overlapped_matmul():
     _run_multidevice("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.parallel import collectives, sharding as shd
+        from repro.parallel import collectives
         mesh = jax.make_mesh((4,), ("x",))
         rng = np.random.default_rng(1)
         xs = jnp.asarray(rng.standard_normal((8, 16)), jnp.float32)
         w = jnp.asarray(rng.standard_normal((16, 4)), jnp.float32)
         def f(x_shard, w):
             return collectives.overlapped_matmul_allgather(x_shard, w, "x")
-        got = shd.shard_map(f, mesh=mesh, in_specs=(P("x"), P()),
+        got = jax.shard_map(f, mesh=mesh, in_specs=(P("x"), P()),
                             out_specs=P(), check_vma=False)(xs, w)
         np.testing.assert_allclose(np.asarray(got), np.asarray(xs @ w), atol=1e-5)
 
         def g(x_shard):
             return collectives.ring_allgather(x_shard, "x")
-        gathered = shd.shard_map(g, mesh=mesh, in_specs=(P("x"),),
+        gathered = jax.shard_map(g, mesh=mesh, in_specs=(P("x"),),
                                  out_specs=P("x"), check_vma=False)(xs)
         assert gathered.shape == (16, 2, 16)
     """)

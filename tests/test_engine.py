@@ -56,27 +56,25 @@ def test_matmul_bitexact_ref_is_oracle(mm):
 
 
 def test_matmul_bitexact_pallas_bit_equal():
-    # Block-aligned shapes: the kernel is bit-equal to the oracle with the
-    # kernel's blocked-k accumulation order (the chooser picks (4, 8, 8)).
+    # The kernel is bit-equal to the oracle: both sum in the defined k order
+    # (the chooser picks the smallest tile-legal block, (8, 8, 128)).
     rng = np.random.default_rng(21)
     x = jnp.asarray(rng.standard_normal((4, 8)).astype(np.float32))
     w = jnp.asarray(rng.standard_normal((8, 8)).astype(np.float32))
     vids = rng.integers(0, 9, (8, 8)).astype(np.int32)
-    block = ops.choose_block("bitexact_matmul", 4, 8, 8)
-    assert block == (4, 8, 8)
+    assert ops.choose_block("bitexact_matmul", 4, 8, 8) == (8, 8, 128)
     y = engine.am_matmul(x, w, vids, backend="bitexact_pallas")
-    want = ref.am_matmul_bitexact_ref(x, w, vids, chunk_k=block[1])
+    want = ref.am_matmul_bitexact_ref(x, w, vids)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(want))
 
 
 def test_matmul_bitexact_pallas_padded_close(mm):
-    # Non-multiple shapes pad to block multiples; padding changes the XLA
-    # reduction tree, so parity is allclose (1-ulp), not bit-equal.
+    # Non-multiple shapes pad to block multiples with exact zero slots,
+    # which leave the defined-order sums unchanged: still bit-equal.
     x, w, vids = mm
     y = engine.am_matmul(x, w, vids, backend="bitexact_pallas")
     want = ref.am_matmul_bitexact_ref(x, w, vids)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=2e-6,
-                               atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(want))
 
 
 @pytest.mark.parametrize("backend", ["surrogate_xla", "surrogate_fused"])
@@ -359,7 +357,7 @@ def test_block_chooser_budgets():
     # candidates over pow2-padded dims).
     for kind, m, k, n, fits in [
         ("bitexact_matmul", 1024, 1024, 1024,
-         lambda b: b[0] * b[1] * b[2] * 1920 <= ops.BITEXACT_VMEM_BUDGET),
+         lambda b: ops._bitexact_live_bytes(*b) <= ops.BITEXACT_VMEM_BUDGET),
         ("surrogate_matmul", 512, 512, 512,
          lambda b: (b[0] * b[1] + 3 * b[1] * b[2] + 3 * b[0] * b[2]) * 4
          <= ops.VMEM_BYTES),
@@ -370,13 +368,15 @@ def test_block_chooser_budgets():
     # tighter budget shrinks the block
     big = ops.choose_block("bitexact_matmul", 1024, 1024, 1024)
     sm = ops.choose_block("bitexact_matmul", 1024, 1024, 1024,
-                          vmem_bytes=1 << 20)
-    assert np.prod(sm) * 1920 <= 1 << 20 and np.prod(sm) < np.prod(big)
+                          vmem_bytes=4 << 20)
+    assert ops._bitexact_live_bytes(*sm) <= 4 << 20
+    assert np.prod(sm) < np.prod(big)
+    # every bit-exact candidate is tile-legal for Mosaic
+    for bm, bk, bn in ops.candidate_blocks("bitexact_matmul", 1024, 1024, 1024):
+        assert bm % 8 == 0 and bk % 128 == 0 and bn % 128 == 0
     bm, bk, bn = ops.choose_block("surrogate_matmul", 512, 512, 512,
-                                  vmem_bytes=96 * 1024)
-    assert (bm * bk + 3 * bk * bn + 3 * bm * bn) * 4 <= 96 * 1024
-    # conv filter grouping: paper CNN layer 2 -> the hand-derived FG=4
-    assert ops.choose_block("bitexact_conv", 900, 3, 12) == 4
+                                  vmem_bytes=256 * 1024)
+    assert (bm * bk + 3 * bk * bn + 3 * bm * bn) * 4 <= 256 * 1024
     # blocks never exceed (the pow2 ceiling of) the problem dims
     bm, bk, bn = ops.choose_block("surrogate_matmul", 5, 12, 7)
     assert bm <= 8 and bk <= 16 and bn <= 8

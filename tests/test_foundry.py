@@ -7,6 +7,7 @@ import pytest
 from repro import foundry
 from repro.core import compressors as C
 from repro.core import engine, fp32_mul, hwmodel, nsga2, schemes, surrogate
+from repro.kernels import ref
 
 CHAR_N = 1 << 12  # characterization sample size for fast tests
 
@@ -228,7 +229,7 @@ def test_bitexact_backends_match_oracle(registered):
         prods = fp32_mul.fp32_multiply(
             jnp.asarray(x[:, :, None]), jnp.asarray(w[None, :, :]),
             jnp.asarray(m))
-        want = np.asarray(jnp.sum(prods, axis=1))
+        want = np.asarray(ref.lane_sum(prods))  # the defined k order
         vids = np.full((6, 5), r.variant_id, np.int32)
         for backend in ("bitexact_ref", "bitexact_pallas"):
             got = np.asarray(engine.am_matmul(x, w, vids, backend=backend))
@@ -269,7 +270,7 @@ def test_pallas_jit_cache_not_stale_across_registration():
         prods = fp32_mul.fp32_multiply(
             jnp.asarray(x[:, :, None]), jnp.asarray(w[None, :, :]),
             jnp.asarray(m))
-        want = np.asarray(jnp.sum(prods, axis=1))
+        want = np.asarray(ref.lane_sum(prods))  # the defined k order
         got = np.asarray(engine.am_matmul(
             x, w, np.full((7, 3), r.variant_id, np.int32),
             backend="bitexact_pallas"))

@@ -84,3 +84,30 @@ def test_interleaved_multiply_matches_per_variant(rng):
         pure = np.asarray(fp32_mul.fp32_multiply_variant(
             jnp.asarray(a[mask]), jnp.asarray(b[mask]), schemes.VARIANTS[v]))
         np.testing.assert_array_equal(mixed[mask], pure)
+
+
+def _random_bits(rng, n):
+    """Uniform float32 bit patterns plus the IEEE specials and subnormals."""
+    sp = np.array([0.0, -0.0, np.inf, -np.inf, np.nan, 1e-40, -1e-42, 1e38,
+                   3e-39, 1.0, -1.0, 2.0 ** -126], np.float32)
+    a_sp, b_sp = (g.ravel() for g in np.meshgrid(sp, sp))
+    bits = rng.integers(0, 1 << 32, (2, n), dtype=np.uint64).astype(np.uint32)
+    return (np.concatenate([bits[0].view(np.float32), a_sp]),
+            np.concatenate([bits[1].view(np.float32), b_sp]))
+
+
+@pytest.mark.parametrize("which", ["seed", "random"])
+def test_packed_multiply_matches_bit_tensor(rng, which):
+    """The kernels' packed (limb + column-mask) spelling is bit-identical to
+    the bit-tensor emulation for every seed variant and for random maps."""
+    a, b = _random_bits(rng, 2048)
+    if which == "seed":
+        maps = schemes.scheme_stack()
+    else:
+        maps = rng.integers(0, 5, (4, 3, 48)).astype(np.int32)
+    masks = fp32_mul.code_masks(maps)
+    for m, words in zip(maps, masks):
+        want = np.asarray(fp32_mul._fp32_multiply_jit(a, b, jnp.asarray(m)))
+        got = np.asarray(jax.jit(fp32_mul.fp32_multiply_packed)(
+            a, b, [jnp.int32(w) for w in words]))
+        np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))

@@ -94,7 +94,7 @@ def test_collective_bytes_on_sharded_matmul():
         def f(x, w):
             y = x @ w                       # w row-sharded -> partial sums
             return jax.lax.with_sharding_constraint(y, ns(P("data", None)))
-        with shd.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             # NamedSharding works on every jax version (bare PartitionSpecs
             # in in_shardings require newer jax).
             co = jax.jit(f, in_shardings=(ns(P("data", "model")),
