@@ -25,6 +25,15 @@ A production-shaped continuous-batching tier over fixed decode slots:
   own tier's moment map inside the one dispatch (core/engine.py::
   register_tier_set / row_tier_context) — premium traffic decodes exact
   while bulk traffic rides aggressive interleaves, in the same batch.
+* **A round's phases are spans.** With observability on (repro.obs), each
+  scheduling round is one ``serve.round`` span holding, in order:
+  ``serve.admit`` (queue pops, and the slot-reset dispatch when a slot is
+  filled), ``serve.pack`` (the step's token rows, then its arguments as
+  device arrays), ``serve.dispatch`` (the step enqueued), ``serve.sync``
+  (the host waiting for the step's tokens) and ``serve.emit`` (tokens
+  appended, requests finished). In per_slot mode each busy row gets its own
+  pack/dispatch/sync. Each request's ``serve.request`` async track runs
+  submit -> ``admit`` -> ``prefill_done`` (its first token) -> end.
 
 Slot isolation: stepping any set of slots updates ONLY those slots' cache
 slices (masked merge per batch row), an admitted request starts from a
@@ -288,37 +297,43 @@ class Server:
                                   donate_argnums=(0,))
 
     def _admit(self):
-        fresh: list[int] = []
-        for i in range(self.slots):
-            if self.active[i] is None and self.queue:
-                req = self.queue.pop(0)
-                self.active[i] = req
-                req.status = "active"
-                obs.async_instant("serve.request", req.rid, "admit", slot=i)
-                self.pos[i] = 0
-                self._fed[i] = 0
-                self._tier_rows[i] = self._tier_id(req)
-                fresh.append(i)
-        if fresh:
-            mask = np.zeros(self.slots, bool)
-            mask[fresh] = True
-            with jax.set_mesh(self.mesh):
-                self.cache = self._jit_reset(self.cache, self._fresh,
-                                             jnp.asarray(mask))
+        with obs.span("serve.admit"):
+            fresh: list[int] = []
+            for i in range(self.slots):
+                if self.active[i] is None and self.queue:
+                    req = self.queue.pop(0)
+                    self.active[i] = req
+                    req.status = "active"
+                    obs.async_instant("serve.request", req.rid, "admit",
+                                      slot=i)
+                    self.pos[i] = 0
+                    self._fed[i] = 0
+                    self._tier_rows[i] = self._tier_id(req)
+                    fresh.append(i)
+            if fresh:
+                mask = np.zeros(self.slots, bool)
+                mask[fresh] = True
+                with jax.set_mesh(self.mesh):
+                    self.cache = self._jit_reset(self.cache, self._fresh,
+                                                 jnp.asarray(mask))
 
     # --- dispatch ----------------------------------------------------------
 
     def _invoke(self, tokens: np.ndarray, lens: np.ndarray) -> np.ndarray:
-        with obs.span("serve.dispatch", mode=self.mode,
-                      rows=int((lens > 0).sum()), chunk=int(tokens.shape[1])), \
-                jax.set_mesh(self.mesh):
-            nxt, self.cache = self._jit_step(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(self.pos), jnp.asarray(lens),
-                jnp.asarray(self._tier_rows))
+        """One step over the rows `lens` selects: its arguments packed into
+        device arrays, the step enqueued, then the host waits for the
+        step's next tokens."""
+        with jax.set_mesh(self.mesh):
+            with obs.span("serve.pack"):
+                args = (jnp.asarray(tokens), jnp.asarray(self.pos),
+                        jnp.asarray(lens), jnp.asarray(self._tier_rows))
+            with obs.span("serve.dispatch", mode=self.mode,
+                          chunk=tokens.shape[1]):
+                nxt, self.cache = self._jit_step(self.params, self.cache,
+                                                 *args)
         self.stats["dispatches"] += 1
-        obs.metrics.counter_inc("serve.dispatches", mode=self.mode)
-        return np.asarray(nxt)
+        with obs.span("serve.sync"):
+            return np.asarray(nxt)
 
     def _round(self, tokens: np.ndarray, lens: np.ndarray) -> np.ndarray:
         """One scheduling round. Batched: ONE dispatch advances every busy
@@ -336,46 +351,51 @@ class Server:
 
     def _prefill_round(self):
         t = self.prefill_chunk
-        tokens = np.zeros((self.slots, t), np.int32)
-        lens = np.zeros(self.slots, np.int32)
-        for i, req in enumerate(self.active):
-            if req is None:
-                continue
-            rem = len(req.prompt) - int(self._fed[i])
-            if rem <= 0:
-                continue
-            nloc = min(rem, t)
-            lo = int(self._fed[i])
-            tokens[i, :nloc] = req.prompt[lo:lo + nloc]
-            lens[i] = nloc
+        with obs.span("serve.pack"):
+            tokens = np.zeros((self.slots, t), np.int32)
+            lens = np.zeros(self.slots, np.int32)
+            for i, req in enumerate(self.active):
+                if req is None:
+                    continue
+                rem = len(req.prompt) - int(self._fed[i])
+                if rem <= 0:
+                    continue
+                nloc = min(rem, t)
+                lo = int(self._fed[i])
+                tokens[i, :nloc] = req.prompt[lo:lo + nloc]
+                lens[i] = nloc
         nxt = self._round(tokens, lens)
-        self.stats["prefill_rounds"] += 1
-        self.stats["prefill_tokens"] += int(lens.sum())
-        for i in np.flatnonzero(lens):
-            req = self.active[i]
-            self._fed[i] += lens[i]
-            self.pos[i] += lens[i]
-            if int(self._fed[i]) == len(req.prompt):
-                # The prediction from the last prompt position IS the first
-                # decode token: the final prompt token is cached exactly once
-                # (prefill's last step), never re-fed.
-                obs.async_instant("serve.request", req.rid, "prefill_done",
-                                  slot=i, prompt_len=len(req.prompt))
-                self._emit(i, int(nxt[i]))
+        with obs.span("serve.emit"):
+            self.stats["prefill_rounds"] += 1
+            self.stats["prefill_tokens"] += int(lens.sum())
+            for i in np.flatnonzero(lens):
+                req = self.active[i]
+                self._fed[i] += lens[i]
+                self.pos[i] += lens[i]
+                if int(self._fed[i]) == len(req.prompt):
+                    # The prediction from the last prompt position IS the
+                    # first decode token: the final prompt token is cached
+                    # exactly once (prefill's last step), never re-fed.
+                    obs.async_instant("serve.request", req.rid,
+                                      "prefill_done", slot=i,
+                                      prompt_len=len(req.prompt))
+                    self._emit(i, int(nxt[i]))
 
     def _decode_tick(self):
-        tokens = np.zeros((self.slots, 1), np.int32)
-        lens = np.zeros(self.slots, np.int32)
-        for i, req in enumerate(self.active):
-            if req is None:
-                continue
-            tokens[i, 0] = req.out[-1]
-            lens[i] = 1
+        with obs.span("serve.pack"):
+            tokens = np.zeros((self.slots, 1), np.int32)
+            lens = np.zeros(self.slots, np.int32)
+            for i, req in enumerate(self.active):
+                if req is None:
+                    continue
+                tokens[i, 0] = req.out[-1]
+                lens[i] = 1
         nxt = self._round(tokens, lens)
-        self.stats["decode_ticks"] += 1
-        for i in np.flatnonzero(lens):
-            self.pos[i] += 1
-            self._emit(i, int(nxt[i]))
+        with obs.span("serve.emit"):
+            self.stats["decode_ticks"] += 1
+            for i in np.flatnonzero(lens):
+                self.pos[i] += 1
+                self._emit(i, int(nxt[i]))
 
     def _emit(self, i: int, tok: int):
         req = self.active[i]
@@ -570,14 +590,15 @@ class Server:
         live on the Request objects (out/status/error)."""
         rounds = 0
         while max_steps is None or rounds < max_steps:
-            self._admit()
-            if not any(r is not None for r in self.active):
+            if not self.queue and not any(r is not None for r in self.active):
                 break
-            if any(r is not None and self._fed[i] < len(r.prompt)
-                   for i, r in enumerate(self.active)):
-                self._prefill_round()
-            else:
-                self._decode_tick()
+            with obs.span("serve.round"):
+                self._admit()
+                if any(r is not None and self._fed[i] < len(r.prompt)
+                       for i, r in enumerate(self.active)):
+                    self._prefill_round()
+                else:
+                    self._decode_tick()
             rounds += 1
         return list(self.finished)
 

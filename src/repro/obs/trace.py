@@ -18,10 +18,15 @@ Convention (enforced by review, asserted in tests where cheap): spans wrap
 HOST-side work only — never the inside of a jitted body, where the Python
 code runs once at trace time and the recorded duration would be
 compilation, not execution. Instrument the call site of the jitted
-function instead. When a JAX profiler session is active, spans also enter
-`jax.profiler.TraceAnnotation` so they land on the XLA timeline
-(`set_jax_bridge(True)`; off by default because the annotation costs a
-TraceMe even with no profiler attached).
+function instead.
+
+One rule puts spans on the profiler's timeline: while observability is on,
+every span also enters `jax.profiler.TraceAnnotation` under its bare name
+(no args, so one phase keeps one label from call to call). Whenever a JAX
+profiler session records, the spans then share the device trace's clock;
+with no session the annotation costs one TraceMe check. While observability
+is off, `span()` imports nothing and enters nothing. The recorded events
+stay on `time.perf_counter`: `origin()` + ts x 1e-6 is a perf_counter time.
 
 `python -m repro.obs.trace --validate f.json ...` validates files against
 the Chrome trace-event schema (the CI gate for exported artifacts).
@@ -42,17 +47,25 @@ _lock = threading.Lock()
 _events: list[dict] = []
 _named_threads: set[int] = set()
 _t0 = time.perf_counter()
-_jax_bridge = False
 
 
-def set_jax_bridge(value: bool) -> None:
-    """Mirror spans into jax.profiler.TraceAnnotation (XLA timeline)."""
-    global _jax_bridge
-    _jax_bridge = bool(value)
+def origin() -> float:
+    """The perf_counter time (seconds) at which event timestamps are 0."""
+    return _t0
 
 
 def _now_us() -> float:
     return (time.perf_counter() - _t0) * 1e6
+
+
+def _annotation(name: str):
+    """An entered jax.profiler.TraceAnnotation named `name` (jax is
+    imported here, by the first span recorded, never by an off span)."""
+    import jax.profiler
+
+    ann = jax.profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
 
 
 def _thread_meta(tid: int) -> list[dict]:
@@ -81,30 +94,22 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "args", "_ts", "_jax_ann")
+    __slots__ = ("name", "args", "_ts", "_ann")
 
     def __init__(self, name: str, args: dict):
         self.name = name
         self.args = args
         self._ts = 0.0
-        self._jax_ann = None
+        self._ann = None
 
     def __enter__(self):
+        self._ann = _annotation(self.name)
         self._ts = _now_us()
-        if _jax_bridge:
-            try:
-                import jax
-
-                self._jax_ann = jax.profiler.TraceAnnotation(self.name)
-                self._jax_ann.__enter__()
-            except Exception:
-                self._jax_ann = None
         return self
 
     def __exit__(self, *exc):
-        if self._jax_ann is not None:
-            self._jax_ann.__exit__(*exc)
         end = _now_us()
+        self._ann.__exit__(*exc)
         tid = threading.get_ident()
         ev = {
             "name": self.name, "ph": "X", "ts": self._ts,
@@ -119,7 +124,8 @@ class _Span:
 
 
 def span(name: str, **args):
-    """A context manager timing one host-side operation (no-op when off)."""
+    """A context manager timing one host-side operation, also entered as a
+    profiler annotation named `name` (the shared no-op when off)."""
     if not config.enabled():
         return _NOOP
     return _Span(name, args)

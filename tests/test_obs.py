@@ -3,6 +3,9 @@ the shared stats-dataclass plumbing, the jit-retrace watchdog (including the
 stale-jit-cache repro it exists to catch), and the async queue_wait_fraction
 zero-dispatch guard."""
 import json
+import os
+import pathlib
+import time
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +51,80 @@ def test_span_disabled_is_shared_noop_and_records_nothing():
     metrics.counter_inc("c")
     assert trace.events() == []
     assert metrics.snapshot()["counters"] == {}
+
+
+class _AnnotationRecorder:
+    """Stands in for jax.profiler.TraceAnnotation; logs enter/exit."""
+
+    log: list = []
+
+    def __init__(self, name, **kwargs):
+        self.name = name
+        self.kwargs = kwargs
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.kwargs))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, self.kwargs))
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax.profiler
+
+    _AnnotationRecorder.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _AnnotationRecorder)
+    return _AnnotationRecorder.log
+
+
+def test_span_enters_profiler_annotation_with_bare_name(annotations):
+    with obs.enabled_scope(True):
+        a = time.perf_counter()
+        with trace.span("serve.round", rows=3, chunk=8):
+            with trace.span("serve.sync"):
+                pass
+        b = time.perf_counter()
+    assert annotations == [("enter", "serve.round", {}),
+                           ("enter", "serve.sync", {}),
+                           ("exit", "serve.sync", {}),
+                           ("exit", "serve.round", {})]
+    evs = {e["name"]: e for e in trace.events() if e["ph"] == "X"}
+    assert evs["serve.round"]["args"] == {"rows": 3, "chunk": 8}
+    start = trace.origin() + evs["serve.round"]["ts"] * 1e-6
+    assert a <= start <= b
+
+
+def test_span_disabled_enters_no_annotation(annotations):
+    assert trace.span("serve.round", rows=3) is trace.span("serve.sync")
+    with trace.span("serve.round"):
+        pass
+    assert annotations == []
+    assert trace.events() == []
+
+
+def test_span_imports_jax_only_when_on():
+    """Off, a span imports nothing; the first span recorded on imports
+    jax.profiler for its annotation (a fresh interpreter)."""
+    import subprocess
+    import sys
+
+    code = ("import sys\n"
+            "from repro.obs import config, trace\n"
+            "with trace.span('x'):\n"
+            "    pass\n"
+            "off = 'jax' in sys.modules\n"
+            "config.set_enabled(True)\n"
+            "with trace.span('x'):\n"
+            "    pass\n"
+            "print(off, 'jax.profiler' in sys.modules)\n")
+    src = str(pathlib.Path(trace.__file__).resolve().parents[2])
+    env = dict(os.environ, PYTHONPATH=src, REPRO_OBS="0")
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True).stdout.split()
+    assert out == ["False", "True"]
 
 
 def test_nested_spans_export_and_validate(tmp_path):
