@@ -870,16 +870,42 @@ class AMEngine:
             return fix(out[0]), fix(out[1])
         return fix(out)
 
+    def _tier_tile_tables(self, policies, gk: int, gn: int):
+        """Per-tier (1 + mu) and sg^2 over the (gk, gn) tile grid.
+
+        A tier policy is None or a string, so it canonicalizes to one
+        variant per (tile_k x tile_n) tile: its map over the tile grid is
+        its map over a (gk, gn) matrix at tile size 1. A None tier is the
+        exact variant everywhere (zero moments: 1 + mu = 1, sg^2 = 0).
+        Returns two (T, gk, gn) float32 arrays.
+        """
+        vids = np.stack([
+            canonical_matmul_map(p, gk, gn, tile_k=1, tile_n=1).vids
+            for p in policies])
+        mu, sg = moment_maps(vids, self.noise_scale)
+        return 1.0 + mu, sg * sg
+
     def _row_tier_matmul(self, x, w, set_name: str, *, key,
                          return_moments: bool = False):
         """Per-row tier-routed surrogate matmul (the serving path).
 
-        Row r computes the surrogate moments under its own tier's folded
-        weights: mean_r = x_r @ (w (1 + mu_t)), var_r = x_r^2 @ (w^2 sg_t^2)
-        with t = tiers[r] from the ambient row_tier_context — one gather +
-        two batched contractions for the whole mixed-tier batch, no per-tier
-        dispatch. A None-policy tier has all-zero moments: its rows come out
-        exact-mean, zero-variance, so premium traffic shares the dispatch.
+        Row r computes the surrogate moments under its own tier t =
+        tiers[r] (from the ambient row_tier_context). A tier's moments are
+        constant over each (tile_k x tile_n) tile, so with K-tile i and
+        the N-tile j(n) of column n:
+
+            mean[r, n] = sum_i (1 + mu[t, i, j(n)]) * P[r, i, n]
+            var[r, n]  = sum_i  sg[t, i, j(n)]^2    * Q[r, i, n]
+
+        where P[r, i, n] = sum_{k in tile i} x[r, k] w[k, n] and Q the same
+        over x^2 and w^2 — the mathematics of x_r @ (w (1 + mu_t)) and
+        x_r^2 @ (w^2 sg_t^2), summed in another order. P and Q are two
+        batched contractions over the K-tiles on the MXU (f32 at HIGHEST
+        precision); only the small (T, gk, gn) tile tables are gathered per
+        row, so no weight copy is folded or gathered. A None-policy tier
+        has zero moments: its rows come out exact-mean, zero-variance, so
+        premium traffic shares the dispatch. Row r's output depends on row
+        r alone.
 
         Noise is drawn PER ROW from fold_in(key, pos[r]) — a function of the
         call key and the request-local position only, never the row/slot
@@ -898,17 +924,29 @@ class AMEngine:
                 f"tiers:{set_name}: x has {x2.shape[0]} rows (lead dims "
                 f"{lead}) but the row_tier_context binds {rows}; per-row "
                 "tier routing needs exactly one matmul row per served slot")
-        vids = np.stack([
-            canonical_matmul_map(p, k, n, tile_k=self.tile_k,
-                                 tile_n=self.tile_n).vids
-            for p in policies])  # (T, K, N) concrete
-        wm, wv = fold_matmul_weights(
-            w, CanonicalMap(vids, True), noise_scale=self.noise_scale)
-        wm_r = jnp.asarray(wm)[tiers]  # (B, K, N): each row's folded weights
-        wv_r = jnp.asarray(wv)[tiers]
-        xf = x2.astype(jnp.float32)
-        mean = jnp.einsum("bk,bkn->bn", xf, wm_r)
-        var = jnp.einsum("bk,bkn->bn", xf * xf, wv_r)
+        obs_metrics.counter_inc("engine.dispatch", op="matmul",
+                                backend="row_tier")
+        tk, tn = self.tile_k, self.tile_n
+        gk, gn = -(-k // tk), -(-n // tn)
+        one_mu, sg2 = self._tier_tile_tables(policies, gk, gn)
+        xf = jnp.pad(x2.astype(jnp.float32), ((0, 0), (0, gk * tk - k)))
+        wf = jnp.pad(w.astype(jnp.float32),
+                     ((0, gk * tk - k), (0, gn * tn - n)))
+        xt = xf.reshape(rows, gk, tk).transpose(1, 0, 2)  # (gk, B, tk)
+        wt = wf.reshape(gk, tk, gn * tn)  # (gk, tk, gn*tn)
+        contract = functools.partial(
+            jax.lax.dot_general,
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+
+        def apply(tab, pq):  # pq (gk, B, gn*tn) -> (B, n)
+            s = jnp.asarray(tab)[tiers].transpose(1, 0, 2)  # (gk, B, gn)
+            pq = pq.reshape(gk, rows, gn, tn) * s[..., None]
+            return pq.sum(0).reshape(rows, gn * tn)[:, :n]
+
+        mean = apply(one_mu, contract(xt, wt))
+        var = apply(sg2, contract(xt * xt, wt * wt))
         if return_moments:
             return mean.reshape(lead + (n,)), var.reshape(lead + (n,))
         _require_key(key, f"tiers:{set_name}")

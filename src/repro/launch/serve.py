@@ -21,10 +21,11 @@ A production-shaped continuous-batching tier over fixed decode slots:
   returned results instead of silently overflowing the KV cache.
 * **Per-request AM policy tiers.** Each request carries a tier name mapped
   to a NumericsConfig slot-map policy (None = exact); the engine's
-  `tiers:<name>` policy routes every projection's batch rows through their
-  own tier's moment map inside the one dispatch (core/engine.py::
-  register_tier_set / row_tier_context) — premium traffic decodes exact
-  while bulk traffic rides aggressive interleaves, in the same batch.
+  `tiers:<name>` policy contracts every projection once per 128x128 tile
+  for the whole batch and scales each row's tiles by its own tier's
+  moments inside the one dispatch (core/engine.py::register_tier_set /
+  row_tier_context / AMEngine._row_tier_matmul) — premium traffic decodes
+  exact while bulk traffic rides aggressive interleaves, in the same batch.
 * **A round's phases are spans.** With observability on (repro.obs), each
   scheduling round is one ``serve.round`` span holding, in order:
   ``serve.admit`` (queue pops, and the slot-reset dispatch when a slot is

@@ -1,6 +1,7 @@
 """Batched continuous-batching server: results, prefill parity, admission,
 one-dispatch ticks, per-request tiers, and the engine's row-tier routing."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -256,31 +257,125 @@ def test_register_tier_set_validation():
     assert "t_unit" in engine.list_tier_sets()
 
 
-def test_row_tier_moments_match_per_policy_maps(rng):
+def _fold_gather_moments(x, w, policies, tiers, tile):
+    """The row-tier moments as first written: each tier's moments folded
+    into a full (T, K, N) weight copy, gathered per row as (B, K, N), and
+    each row contracted against its own copy. Oracle only."""
+    k, n = w.shape
+    vids = np.stack([engine.canonical_matmul_map(
+        p, k, n, tile_k=tile, tile_n=tile).vids for p in policies])
+    wm, wv = engine.fold_matmul_weights(w, engine.CanonicalMap(vids, True))
+    xf = np.asarray(x, np.float32)
+    t = np.asarray(tiers)
+    mean = np.einsum("bk,bkn->bn", xf, wm[t])
+    var = np.einsum("bk,bkn->bn", xf * xf, wv[t])
+    return mean, var
+
+
+_ORACLE_TIERS = (None, "uniform:pm_csi", "rr:8")
+
+
+@pytest.mark.parametrize("case", [
+    "per_policy",
+    "oracle-16x8-t8",
+    "oracle-200x300-t128",
+    "oracle-256x384-t128",
+])
+def test_row_tier_moments_match_per_policy_maps(rng, case):
     """Row r's tier-routed moments equal the plain surrogate moments under
-    row r's own policy; the None tier is exact-mean zero-variance."""
-    k, n = 16, 8
-    x = jnp.asarray(rng.standard_normal((2, k)).astype(np.float32))
+    row r's own policy; the None tier is exact-mean zero-variance. The
+    tile-factored contraction also matches the fold-and-gather formula on
+    a mixed batch, at tile-aligned and padded (non-dividing) shapes."""
+    if case == "per_policy":
+        k, n = 16, 8
+        x = jnp.asarray(rng.standard_normal((2, k)).astype(np.float32))
+        w = jnp.asarray(rng.standard_normal((k, n)).astype(np.float32))
+        engine.register_tier_set("t_mom", (None, "uniform:pm_csi"),
+                                 overwrite=True)
+        eng = engine.AMEngine(backend="surrogate_xla", tile_k=8, tile_n=8)
+        tiers = jnp.asarray([0, 1], jnp.int32)
+        pos = jnp.asarray([0, 0], jnp.int32)
+        with engine.row_tier_context(tiers, pos):
+            mean, var = eng.matmul(x, w, "tiers:t_mom",
+                                   key=jax.random.PRNGKey(0),
+                                   return_moments=True)
+        np.testing.assert_allclose(np.asarray(mean[0]), np.asarray(x[0] @ w),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(var[0]), 0.0, atol=1e-7)
+        m1, v1 = eng.matmul(x[1:], w, "uniform:pm_csi",
+                            key=jax.random.PRNGKey(0), return_moments=True)
+        np.testing.assert_allclose(np.asarray(mean[1]), np.asarray(m1[0]),
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(var[1]), np.asarray(v1[0]),
+                                   rtol=1e-5, atol=1e-8)
+        assert float(jnp.max(var[1])) > 0.0
+        return
+    shape, tile = case.split("-")[1:]
+    k, n = (int(d) for d in shape.split("x"))
+    tile = int(tile[1:])
+    b = 6
+    x = jnp.asarray(rng.standard_normal((b, k)).astype(np.float32))
     w = jnp.asarray(rng.standard_normal((k, n)).astype(np.float32))
-    engine.register_tier_set("t_mom", (None, "uniform:pm_csi"),
-                             overwrite=True)
-    eng = engine.AMEngine(backend="surrogate_xla", tile_k=8, tile_n=8)
-    tiers = jnp.asarray([0, 1], jnp.int32)
-    pos = jnp.asarray([0, 0], jnp.int32)
+    engine.register_tier_set("t_oracle", _ORACLE_TIERS, overwrite=True)
+    eng = engine.AMEngine(tile_k=tile, tile_n=tile)
+    tiers = jnp.asarray(np.arange(b) % len(_ORACLE_TIERS), jnp.int32)
+    pos = jnp.arange(b, dtype=jnp.int32)
     with engine.row_tier_context(tiers, pos):
-        mean, var = eng.matmul(x, w, "tiers:t_mom",
+        mean, var = eng.matmul(x, w, "tiers:t_oracle",
                                key=jax.random.PRNGKey(0),
                                return_moments=True)
-    np.testing.assert_allclose(np.asarray(mean[0]), np.asarray(x[0] @ w),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(var[0]), 0.0, atol=1e-7)
-    m1, v1 = eng.matmul(x[1:], w, "uniform:pm_csi",
-                        key=jax.random.PRNGKey(0), return_moments=True)
-    np.testing.assert_allclose(np.asarray(mean[1]), np.asarray(m1[0]),
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(var[1]), np.asarray(v1[0]),
-                               rtol=1e-5, atol=1e-8)
-    assert float(jnp.max(var[1])) > 0.0
+    ref_m, ref_v = _fold_gather_moments(x, w, _ORACLE_TIERS, tiers, tile)
+    # Mean entries near zero cancel: scale their tolerance by |x| @ |w|.
+    scale = float(np.max(np.abs(np.asarray(x)) @ np.abs(np.asarray(w))))
+    np.testing.assert_allclose(np.asarray(mean), ref_m, rtol=1e-5,
+                               atol=1e-5 * scale)
+    # Variance terms are all >= 0: no cancellation, relative error only.
+    np.testing.assert_allclose(np.asarray(var), ref_v, rtol=1e-5, atol=0)
+    assert np.all(np.asarray(var)[tiers == 0] == 0.0)
+    assert np.all(np.asarray(var)[tiers != 0] > 0.0)
+
+
+def _constant_bytes(hlo_text: str) -> list[int]:
+    """Bytes of every constant in a lowered module's text."""
+    width = {"f32": 4, "i32": 4, "ui32": 4, "bf16": 2, "f16": 2, "i8": 1,
+             "ui8": 1, "i1": 1, "i64": 8, "ui64": 8, "f64": 8}
+    sizes = []
+    for m in re.finditer(r"stablehlo\.constant .*?: tensor<([^>]*)>",
+                         hlo_text):
+        *dims, dtype = m.group(1).split("x")
+        sizes.append(int(np.prod([int(d) for d in dims])) * width[dtype])
+    return sizes
+
+
+def test_tiered_step_lowers_without_weight_copies():
+    """The tiered decode step at the published xlstm-125m widths (16
+    slots, one token) carries no folded weight copies: no constant over
+    1 MiB, no f32 (3|16, 768, 50304) value, and one row-tier dispatch per
+    AM projection of the traced step (20 in the block scan body, plus the
+    head). Lowered only, never compiled."""
+    from repro import obs
+    from repro.obs import metrics
+
+    cfg = R.get("xlstm-125m").config
+    slots = 16
+    server = Server(cfg, _mesh(), slots=slots, ctx=512, seed=0,
+                    tiers=dict(DEFAULT_TIER_POLICIES))
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    args = (jax.eval_shape(lambda: server.params),
+            jax.eval_shape(lambda: server.cache),
+            jax.ShapeDtypeStruct((slots, 1), jnp.int32), vec, vec, vec)
+    labels = {"op": "matmul", "backend": "row_tier"}
+    with obs.enabled_scope(True):
+        before = metrics.REGISTRY.get_counter("engine.dispatch", **labels)
+        lowered = server._jit_step.lower(*args)
+        dispatches = (metrics.REGISTRY.get_counter("engine.dispatch", **labels)
+                      - before)
+    assert dispatches == 21
+    text = lowered.as_text()
+    sizes = _constant_bytes(text)
+    assert sizes and max(sizes) <= 1 << 20, max(sizes)
+    for lead in (3, slots):
+        assert f"tensor<{lead}x768x50304xf32>" not in text
 
 
 def test_row_tier_requires_context_and_row_match(rng):
