@@ -22,6 +22,10 @@ Prints the run's result line, then one JSON object:
   spans_per_round  spans recorded per serve.round
   phase_ms         per serve.* span name, its time in the window's rounds
                    over their count ("between" is the rest of a round)
+  overlapped_share the server's stats["overlapped"] / stats["dispatches"]
+                   over the window: the share of rounds enqueued while the
+                   previous round's tokens were unread (None for a server
+                   without the counter)
   idle_gaps        the breakdown's gaps, relabelled
 
 A wait still open at the window's close counts to the close.
@@ -93,6 +97,12 @@ def request_waits(marks: dict[int, dict], requests: list[dict], lo: float,
     return out or None
 
 
+def overlapped_share(stats: dict | None) -> float | None:
+    if not stats or "overlapped" not in stats or not stats["dispatches"]:
+        return None
+    return stats["overlapped"] / stats["dispatches"]
+
+
 def phases(rec, events: list[dict], origin: float) -> dict:
     """The numbers this script prints, from a run's Record and the events
     the window recorded."""
@@ -123,6 +133,8 @@ def phases(rec, events: list[dict], origin: float) -> dict:
         if outside else None,
         "spans_per_round": len(spans) / len(rounds) if rounds else None,
         "phase_ms": phase_ms or None,
+        "overlapped_share": overlapped_share(
+            getattr(rec, "counters", {}).get("server_stats")),
     }
     if rec.trace is not None:
         out["idle_gaps"] = [[n, s] for n, s in rec.trace.idle_gaps]
@@ -132,21 +144,24 @@ def phases(rec, events: list[dict], origin: float) -> dict:
 def run_traced(cell, devs, *, seed: int, seconds: float, trace: bool,
                t_start: float):
     """One run of `cell` with observability on over its window; returns
-    (the Record, the window's events, the trace origin)."""
+    (the Record, the window's events, the trace origin). The Record's
+    counters also hold the server's `stats` at the window's close."""
     from repro import obs
 
     driver = cell.driver
     window = driver.window
     events: list[dict] = []
+    stats: dict = {}
 
-    def traced_window(*args, **kwargs):
+    def traced_window(server, *args, **kwargs):
         with obs.enabled_scope(True):
             obs.trace.reset()
             try:
-                return window(*args, **kwargs)
+                return window(server, *args, **kwargs)
             finally:
                 events.extend(obs.trace.events())
                 obs.trace.reset()
+                stats.update(server.stats)
 
     xplane = harness.load_module(harness.HERE / "xplane.py")
     prefix = xplane.SPAN_PREFIX
@@ -156,6 +171,7 @@ def run_traced(cell, devs, *, seed: int, seconds: float, trace: bool,
                          t_start=t_start)
     finally:
         driver.window, xplane.SPAN_PREFIX = window, prefix
+    rec.counters["server_stats"] = stats
     return rec, events, obs.trace.origin()
 
 

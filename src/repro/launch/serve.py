@@ -26,15 +26,23 @@ A production-shaped continuous-batching tier over fixed decode slots:
   moments inside the one dispatch (core/engine.py::register_tier_set /
   row_tier_context / AMEngine._row_tier_matmul) — premium traffic decodes
   exact while bulk traffic rides aggressive interleaves, in the same batch.
+* **One round in flight.** Each round dispatches the next step before it
+  reads the previous one's tokens: the host's admission, packing and
+  emission overlap the step on the device. A decode tick takes each row's
+  token from ``last``, the step's own previous prediction kept on the
+  device, so nothing the host has not read yet is needed to enqueue it.
 * **A round's phases are spans.** With observability on (repro.obs), each
   scheduling round is one ``serve.round`` span holding, in order:
   ``serve.admit`` (queue pops, and the slot-reset dispatch when a slot is
-  filled), ``serve.pack`` (the step's token rows, then its arguments as
-  device arrays), ``serve.dispatch`` (the step enqueued), ``serve.sync``
-  (the host waiting for the step's tokens) and ``serve.emit`` (tokens
-  appended, requests finished). In per_slot mode each busy row gets its own
-  pack/dispatch/sync. Each request's ``serve.request`` async track runs
-  submit -> ``admit`` -> ``prefill_done`` (its first token) -> end.
+  filled), ``serve.pack`` (the next step's token rows, then its arguments
+  as device arrays) and ``serve.dispatch`` (the step enqueued), then
+  ``serve.sync`` (the host waiting for the previous step's tokens) and
+  ``serve.emit`` (tokens appended, requests finished). The first round of
+  a busy period has no sync or emit; a round with nothing left to
+  dispatch next also syncs and emits its own step. In per_slot mode each
+  busy row gets its own pack/dispatch/sync, read within the round. Each
+  request's ``serve.request`` async track runs submit -> ``admit`` ->
+  ``prefill_done`` (its first token read) -> end.
 
 Slot isolation: stepping any set of slots updates ONLY those slots' cache
 slices (masked merge per batch row), an admitted request starts from a
@@ -90,6 +98,16 @@ class Request:
     @property
     def latency(self) -> float:
         return self.finished_at - self.submitted_at
+
+
+@dataclasses.dataclass
+class _Round:
+    """A dispatched round: its step outputs (rows, next tokens still on the
+    device) and the requests its tokens go to, fixed at dispatch."""
+    kind: str  # prefill | decode
+    nxt: list[tuple[np.ndarray | list[int], jax.Array]]
+    emits: list[tuple[int, Request]]
+    fed: int  # tokens the round fed
 
 
 class Server:
@@ -171,10 +189,16 @@ class Server:
         self.pos = np.zeros(slots, np.int32)       # tokens written per slot
         self._fed = np.zeros(slots, np.int32)      # prompt tokens consumed
         self._tier_rows = np.zeros(slots, np.int32)
+        # Each row's most recent prediction, on the device: a decode tick
+        # feeds it back without the host reading it first.
+        self._last = jax.device_put(np.zeros(slots, np.int32),
+                                    NamedSharding(mesh, PartitionSpec()))
+        # The dispatched round whose tokens the host has not read yet.
+        self._inflight: _Round | None = None
         self.queue: list[Request] = []
         self.finished: list[Request] = []
         self.stats = {"dispatches": 0, "decode_ticks": 0, "prefill_rounds": 0,
-                      "generated": 0, "prefill_tokens": 0}
+                      "generated": 0, "prefill_tokens": 0, "overlapped": 0}
         # Surrogate noise: ONE key for the whole server, closed over by the
         # jitted step (concrete, so callsite fold_in chains constant-fold).
         # The engine folds in each row's request-local position (never the
@@ -192,16 +216,21 @@ class Server:
         noise_key = self._noise_key
         batch_axes = self._batch_axes
 
-        def step(params, cache, tokens, pos0, lens, tiers):
+        def step(params, cache, last, tokens, feed_last, pos0, lens, tiers):
             """Advance row r through tokens[r, :lens[r]] (lens[r]=0: idle).
 
-            tokens (B, T) i32, pos0/lens/tiers (B,) i32. Returns
-            (next_token (B,), cache): next_token[r] is the greedy prediction
-            from row r's LAST fed token (-1 for idle rows). T=1 with
+            tokens (B, T) i32; last (B,) i32, each row's most recent
+            prediction; feed_last (B,) bool: the row's token 0 is
+            last[r], not tokens[r, 0]; pos0/lens/tiers (B,) i32. Returns
+            (next_token (B,), last, cache): next_token[r] is the greedy
+            prediction from row r's LAST fed token (-1 for idle rows), and
+            last keeps a row's old value where next_token is -1. T=1 with
             lens=live is one decode tick; T=prefill_chunk is batched
             prefill. One dispatch either way.
             """
             t_chunk = tokens.shape[1]
+            tokens = tokens.at[:, 0].set(
+                jnp.where(feed_last, last, tokens[:, 0]))
 
             def body(carry, t):
                 cache, nxt = carry
@@ -230,11 +259,12 @@ class Server:
 
             init = (cache, jnp.full((tokens.shape[0],), -1, jnp.int32))
             (cache, nxt), _ = jax.lax.scan(body, init, jnp.arange(t_chunk))
-            return nxt, cache
+            return nxt, jnp.where(nxt >= 0, nxt, last), cache
 
         # Exactly 2 traces per instance: T=prefill_chunk and T=1. More means
         # shape churn; fewer after a numerics change means a stale cache.
-        return watchdog.watch_jit(step, name="serve.step", donate_argnums=(1,))
+        return watchdog.watch_jit(step, name="serve.step",
+                                  donate_argnums=(1, 2))
 
     # --- request lifecycle -------------------------------------------------
 
@@ -320,83 +350,116 @@ class Server:
 
     # --- dispatch ----------------------------------------------------------
 
-    def _invoke(self, tokens: np.ndarray, lens: np.ndarray) -> np.ndarray:
+    def _pending(self, i: int) -> str | None:
+        """What row i still has to dispatch, from host state alone: the
+        rest of its prompt, one more decode tick, or nothing (its last
+        token is dispatched, maybe not yet read)."""
+        req = self.active[i]
+        if req is None:
+            return None
+        if self._fed[i] < len(req.prompt):
+            return "prefill"
+        if self.pos[i] < len(req.prompt) + req.max_new - 1:
+            return "decode"
+        return None
+
+    def _has_work(self) -> bool:
+        """Whether the next round will have something to dispatch."""
+        return (any(self._pending(i) for i in range(self.slots))
+                or (bool(self.queue)
+                    and any(r is None for r in self.active)))
+
+    def _enqueue(self, tokens: np.ndarray, lens: np.ndarray,
+                 feed_last: np.ndarray) -> jax.Array:
         """One step over the rows `lens` selects: its arguments packed into
-        device arrays, the step enqueued, then the host waits for the
-        step's next tokens."""
+        device arrays (fresh host copies, so advancing `pos` after the
+        enqueue cannot reach the step), the step enqueued. Returns the
+        step's next tokens, still on the device."""
+        if self._inflight is not None:
+            self.stats["overlapped"] += 1
         with jax.set_mesh(self.mesh):
             with obs.span("serve.pack"):
-                args = (jnp.asarray(tokens), jnp.asarray(self.pos),
-                        jnp.asarray(lens), jnp.asarray(self._tier_rows))
+                args = (jnp.asarray(tokens), jnp.asarray(feed_last),
+                        jnp.asarray(self.pos.copy()), jnp.asarray(lens),
+                        jnp.asarray(self._tier_rows.copy()))
             with obs.span("serve.dispatch", mode=self.mode,
                           chunk=tokens.shape[1]):
-                nxt, self.cache = self._jit_step(self.params, self.cache,
-                                                 *args)
+                nxt, self._last, self.cache = self._jit_step(
+                    self.params, self.cache, self._last, *args)
         self.stats["dispatches"] += 1
-        with obs.span("serve.sync"):
-            return np.asarray(nxt)
+        return nxt
 
-    def _round(self, tokens: np.ndarray, lens: np.ndarray) -> np.ndarray:
-        """One scheduling round. Batched: ONE dispatch advances every busy
-        row. per_slot: the same executable once per busy row, single-row
-        lens mask (the reference/baseline; bitwise identical per row since
-        every decode op is row-local)."""
-        if self.mode == "batched":
-            return self._invoke(tokens, lens)
-        out = np.full(self.slots, -1, np.int32)
-        for i in np.flatnonzero(lens):
-            solo = np.zeros_like(lens)
-            solo[i] = lens[i]
-            out[i] = self._invoke(tokens, solo)[i]
-        return out
-
-    def _prefill_round(self):
-        t = self.prefill_chunk
+    def _dispatch(self) -> _Round | None:
+        """Pack and enqueue the next round from host state alone: a prefill
+        round while any row has prompt left (decode rows sit idle), else a
+        decode tick whose rows take their token from the device's `last`.
+        Advances `pos` and the prompt cursor at dispatch. Batched: ONE
+        dispatch advances every busy row. per_slot: the same executable
+        once per busy row, single-row lens mask (the reference; bitwise
+        identical per row since every decode op is row-local)."""
+        want = [self._pending(i) for i in range(self.slots)]
+        if not any(want):
+            return None
+        kind = "prefill" if "prefill" in want else "decode"
+        t = self.prefill_chunk if kind == "prefill" else 1
         with obs.span("serve.pack"):
             tokens = np.zeros((self.slots, t), np.int32)
             lens = np.zeros(self.slots, np.int32)
-            for i, req in enumerate(self.active):
-                if req is None:
+            emits: list[tuple[int, Request]] = []
+            for i, w in enumerate(want):
+                if w != kind:
                     continue
-                rem = len(req.prompt) - int(self._fed[i])
-                if rem <= 0:
+                req = self.active[i]
+                if kind == "decode":
+                    lens[i] = 1
+                    emits.append((i, req))
                     continue
-                nloc = min(rem, t)
                 lo = int(self._fed[i])
+                nloc = min(len(req.prompt) - lo, t)
                 tokens[i, :nloc] = req.prompt[lo:lo + nloc]
                 lens[i] = nloc
-        nxt = self._round(tokens, lens)
-        with obs.span("serve.emit"):
-            self.stats["prefill_rounds"] += 1
-            self.stats["prefill_tokens"] += int(lens.sum())
-            for i in np.flatnonzero(lens):
-                req = self.active[i]
-                self._fed[i] += lens[i]
-                self.pos[i] += lens[i]
-                if int(self._fed[i]) == len(req.prompt):
+                if lo + nloc == len(req.prompt):
                     # The prediction from the last prompt position IS the
                     # first decode token: the final prompt token is cached
                     # exactly once (prefill's last step), never re-fed.
+                    emits.append((i, req))
+            feed_last = np.full(self.slots, kind == "decode")
+        if self.mode == "batched":
+            nxt = [(np.flatnonzero(lens), self._enqueue(tokens, lens,
+                                                        feed_last))]
+        else:
+            nxt = []
+            for i in np.flatnonzero(lens):
+                solo = np.zeros_like(lens)
+                solo[i] = lens[i]
+                nxt.append(([i], self._enqueue(tokens, solo, feed_last)))
+        if kind == "prefill":
+            self._fed += lens
+        self.pos += lens
+        return _Round(kind, nxt, emits, int(lens.sum()))
+
+    def _read(self, rnd: _Round):
+        """Wait for a dispatched round's tokens, then emit each to the
+        request it was dispatched for and count the round."""
+        out = np.full(self.slots, -1, np.int32)
+        for rows, nxt in rnd.nxt:
+            with obs.span("serve.sync"):
+                out[rows] = np.asarray(nxt)[rows]
+        with obs.span("serve.emit"):
+            if rnd.kind == "prefill":
+                self.stats["prefill_rounds"] += 1
+                self.stats["prefill_tokens"] += rnd.fed
+            else:
+                self.stats["decode_ticks"] += 1
+            for i, req in rnd.emits:
+                # A request holds its slot until its last token is read, so
+                # the slot still holds the request the round was for.
+                assert self.active[i] is req, (i, req.rid)
+                if rnd.kind == "prefill":
                     obs.async_instant("serve.request", req.rid,
                                       "prefill_done", slot=i,
                                       prompt_len=len(req.prompt))
-                    self._emit(i, int(nxt[i]))
-
-    def _decode_tick(self):
-        with obs.span("serve.pack"):
-            tokens = np.zeros((self.slots, 1), np.int32)
-            lens = np.zeros(self.slots, np.int32)
-            for i, req in enumerate(self.active):
-                if req is None:
-                    continue
-                tokens[i, 0] = req.out[-1]
-                lens[i] = 1
-        nxt = self._round(tokens, lens)
-        with obs.span("serve.emit"):
-            self.stats["decode_ticks"] += 1
-            for i in np.flatnonzero(lens):
-                self.pos[i] += 1
-                self._emit(i, int(nxt[i]))
+                self._emit(i, int(out[i]))
 
     def _emit(self, i: int, tok: int):
         req = self.active[i]
@@ -588,18 +651,29 @@ class Server:
         """Drive the schedule until all submitted work finishes (or
         ``max_steps`` scheduling rounds elapse). Returns every finished
         request — completed AND rejected, in finish order; results also
-        live on the Request objects (out/status/error)."""
+        live on the Request objects (out/status/error).
+
+        A round admits into free slots, dispatches the next round from
+        host state, then reads the previous round's tokens, so the host's
+        work overlaps the step in flight. A round that leaves nothing to
+        dispatch next reads its own round too: run() never returns owing
+        tokens with the queue and every slot empty. A request holds its
+        slot until its last token is read; per_slot mode reads each round
+        within it. Rounds are counted in ``stats`` when read."""
         rounds = 0
         while max_steps is None or rounds < max_steps:
             if not self.queue and not any(r is not None for r in self.active):
                 break
             with obs.span("serve.round"):
                 self._admit()
-                if any(r is not None and self._fed[i] < len(r.prompt)
-                       for i, r in enumerate(self.active)):
-                    self._prefill_round()
-                else:
-                    self._decode_tick()
+                rnd = self._dispatch()
+                if self._inflight is not None:
+                    self._read(self._inflight)
+                self._inflight = rnd
+                if rnd is not None and (self.mode == "per_slot"
+                                        or not self._has_work()):
+                    self._read(rnd)
+                    self._inflight = None
             rounds += 1
         return list(self.finished)
 

@@ -1,8 +1,9 @@
 """The server's phase spans and request tracks (repro.obs): one serve.round
-per scheduling round holding its admit / pack / dispatch / sync / emit
-phases, each request's serve.request track on the caller's perf_counter
-clock, the same tokens with tracing on and off, and the spans on a JAX
-profiler timeline under their bare names."""
+per scheduling round holding its admit, then the next step's pack /
+dispatch, then the previous step's sync / emit; each request's
+serve.request track on the caller's perf_counter clock, the same tokens
+with tracing on and off, and the spans on a JAX profiler timeline under
+their bare names."""
 import collections
 import glob
 import time
@@ -92,23 +93,46 @@ def test_one_round_span_per_round_holding_its_phases(served, mode):
     assert len(rounds) == len(s["rounds"]) == (
         s["stats"]["prefill_rounds"] + s["stats"]["decode_ticks"])
     inside = collections.Counter()
+    where = collections.defaultdict(list)
     for name in PHASES:
         for a, b in _spans(s["events"], name):
             hits = [i for i, (ra, rb) in enumerate(rounds)
                     if ra <= a and b <= rb]
             assert len(hits) == 1, (name, a, b)
             inside[name, hits[0]] += 1
+            where[name, hits[0]].append((a, b))
+    owed = 0  # rounds dispatched and not yet read
     for i in range(len(rounds)):
+        dispatch, sync, emit, pack = (inside[n, i] for n in (
+            "serve.dispatch", "serve.sync", "serve.emit", "serve.pack"))
         assert inside["serve.admit", i] == 1
-        assert inside["serve.emit", i] == 1
-        assert inside["serve.sync", i] == inside["serve.dispatch", i] >= 1
-        assert inside["serve.pack", i] == 1 + inside["serve.dispatch", i]
+        admit_end = where["serve.admit", i][0][1]
+        assert all(admit_end <= a for n in PHASES if n != "serve.admit"
+                   for a, _ in where[n, i])
+        # Round t+1 is packed and dispatched before round t is read.
+        assert all(b <= a for _, b in where["serve.dispatch", i]
+                   for a, _ in where["serve.sync", i])
+        if mode == "per_slot":
+            assert sync == dispatch >= 1 and emit == 1
+            assert pack == 1 + dispatch
+            continue
+        assert dispatch <= 1 and pack == 2 * dispatch and sync == emit
+        if owed:
+            assert sync >= 1  # the round in flight is read
+        else:
+            assert sync in (0, dispatch)  # a busy period's first round
+        owed += dispatch - sync
+        assert owed in (0, 1)
+    assert owed == 0
     syncs = len(_spans(s["events"], "serve.sync"))
     assert syncs == s["stats"]["dispatches"]
     if mode == "batched":
         assert syncs == len(rounds)
+        assert inside["serve.sync", 0] == 0 < inside["serve.dispatch", 0]
+        assert s["stats"]["overlapped"] > 0
     else:
         assert syncs > len(rounds)  # one per busy row
+        assert s["stats"]["overlapped"] == 0
 
 
 @pytest.mark.parametrize("mode", MODES)
@@ -165,6 +189,9 @@ def test_profiler_timeline_holds_round_and_sync(served, tmp_path):
     with obs.enabled_scope(True):
         try:
             server.submit(Request(rid=100, prompt=prompt, max_new=2))
+            # The second round: it dispatches the prompt's last chunk,
+            # then reads the first (the first round only dispatches).
+            server.run(max_steps=1)
             with jax.profiler.trace(str(tmp_path)):
                 server.run(max_steps=1)
             server.run()

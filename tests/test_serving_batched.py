@@ -190,6 +190,133 @@ def test_one_dispatch_per_tick():
 
 
 # ---------------------------------------------------------------------------
+# One round in flight: a load generator's schedule, the overlap counter
+# ---------------------------------------------------------------------------
+
+# (call at which it is submitted, prompt length, max_new, tier). With
+# prefill_chunk 4 and 2 slots: prompts that end mid-chunk, a queue behind
+# full slots, and an idle stretch before the last arrival.
+_LOADGEN_PLAN = ((0, 5, 4, "conservative"), (0, 9, 3, "exact"),
+                (2, 3, 5, "aggressive"), (3, 6, 2, "conservative"),
+                (4, 2, 1, "exact"), (40, 7, 3, "aggressive"))
+
+
+def _loadgen_requests(cfg):
+    rng = np.random.default_rng(5)
+    return [(at, Request(rid=i, max_new=m, tier=tier,
+                         prompt=rng.integers(0, cfg.vocab, n).astype(np.int32)))
+            for i, (at, n, m, tier) in enumerate(_LOADGEN_PLAN)]
+
+
+def _served_so_far(server, req):
+    """The positions a request has advanced, as a load generator works it
+    out: the slot's pos while it holds one, else prompt + out - 1."""
+    for i, r in enumerate(server.active):
+        if r is req:
+            return int(server.pos[i])
+    return len(req.prompt) + len(req.out) - 1 if req.done else 0
+
+
+def _drive_one_round_at_a_time(server, plan):
+    """Submit each request at its call, then one run(max_steps=1) per call
+    while there is work; checks the invariants a load generator relies on
+    after every call. Returns the calls at which a request was admitted into
+    a slot its predecessor left in the call before."""
+    reused, freed_at = [], {}
+    seen = {r.rid: 0 for _, r in plan}
+    call = 0
+    while call <= max(at for at, _ in plan) or server.queue or any(
+            r is not None for r in server.active):
+        for at, r in plan:
+            if at == call:
+                server.submit(r)
+        holders = list(server.active)
+        if server.queue or any(r is not None for r in server.active):
+            server.run(max_steps=1)
+        for i, (old, new) in enumerate(zip(holders, server.active)):
+            if old is not None and new is not old:
+                freed_at[i] = call
+            if new is not None and new is not old:
+                if freed_at.get(i) == call - 1:
+                    reused.append(call)
+        for _, r in plan:
+            if r.status in ("new", "queued"):
+                continue
+            slot = [i for i, x in enumerate(server.active) if x is r]
+            if not slot:
+                # A request leaves its slot only with its last token read.
+                assert r.done and len(r.out) == r.max_new, r.rid
+            else:
+                i = slot[0]
+                issued = max(0, int(server.pos[i]) - len(r.prompt) + 1)
+                assert 0 <= issued - len(r.out) <= 1, r.rid  # one in flight
+            p = _served_so_far(server, r)
+            assert p >= seen[r.rid], r.rid
+            seen[r.rid] = p
+        if not server.queue and not any(r is not None for r in server.active):
+            assert all(r.done for at, r in plan if at <= call)
+        call += 1
+    for _, r in plan:
+        assert seen[r.rid] == len(r.prompt) + r.max_new - 1, r.rid
+    return reused
+
+
+def test_loadgen_schedule_serves_the_same_tokens_in_every_mode():
+    """Staggered submits between run(max_steps=1) calls over mixed tiers:
+    every request serves bitwise the same tokens batched, per_slot and
+    alone, and a slot freed by a request's last token is filled in the next
+    round."""
+    cfg = R.get("xlstm-125m").smoke
+    tiers = dict(DEFAULT_TIER_POLICIES)
+    outs = {}
+    for mode in ("batched", "per_slot"):
+        server = Server(cfg, _mesh(), slots=2, ctx=32, seed=0, tiers=tiers,
+                        mode=mode, prefill_chunk=4)
+        plan = _loadgen_requests(cfg)
+        reused = _drive_one_round_at_a_time(server, plan)
+        assert reused, mode
+        outs[mode] = [r.out for _, r in plan]
+        assert server.stats["generated"] == sum(m for *_, m, _ in _LOADGEN_PLAN)
+    alone = Server(cfg, _mesh(), slots=2, ctx=32, seed=0, tiers=tiers,
+                   prefill_chunk=4)
+    outs["alone"] = []
+    for _, r in _loadgen_requests(cfg):
+        alone.submit(r)
+        alone.run()
+        outs["alone"].append(r.out)
+    assert outs["batched"] == outs["per_slot"] == outs["alone"]
+    assert all(len(o) == m for o, (*_, m, _) in zip(outs["alone"],
+                                                    _LOADGEN_PLAN))
+
+
+@pytest.mark.parametrize("mode", ["batched", "per_slot"])
+def test_overlap_counter_and_budgets(mode):
+    """Batched: every dispatch but the first of each busy period is
+    enqueued with the previous round unread; per_slot reads each round
+    within it. One dispatch per round in batched mode, and the step traces
+    exactly twice per Server."""
+    from repro.obs import watchdog
+
+    cfg = R.get("xlstm-125m").smoke
+    server = Server(cfg, _mesh(), slots=4, ctx=32, seed=0, mode=mode,
+                    prefill_chunk=4)
+    for period in (1, 2):
+        for i, p in enumerate(_prompts(cfg, 3, 6, seed=period)):
+            server.submit(Request(rid=10 * period + i, prompt=p,
+                                  max_new=10 + i))
+        server.run()
+        st = server.stats
+        assert not server.queue and all(r is None for r in server.active)
+        if mode == "batched":
+            assert st["dispatches"] == st["decode_ticks"] + st["prefill_rounds"]
+            assert st["overlapped"] == st["dispatches"] - period
+            assert st["overlapped"] / st["dispatches"] >= 0.9
+        else:
+            assert st["overlapped"] == 0
+    watchdog.assert_retraces(server._jit_step, 2)
+
+
+# ---------------------------------------------------------------------------
 # Mixed-tier determinism: output independent of slot, schedule, neighbors
 # ---------------------------------------------------------------------------
 
@@ -362,8 +489,9 @@ def test_tiered_step_lowers_without_weight_copies():
                     tiers=dict(DEFAULT_TIER_POLICIES))
     vec = jax.ShapeDtypeStruct((slots,), jnp.int32)
     args = (jax.eval_shape(lambda: server.params),
-            jax.eval_shape(lambda: server.cache),
-            jax.ShapeDtypeStruct((slots, 1), jnp.int32), vec, vec, vec)
+            jax.eval_shape(lambda: server.cache), vec,
+            jax.ShapeDtypeStruct((slots, 1), jnp.int32),
+            jax.ShapeDtypeStruct((slots,), jnp.bool_), vec, vec, vec)
     labels = {"op": "matmul", "backend": "row_tier"}
     with obs.enabled_scope(True):
         before = metrics.REGISTRY.get_counter("engine.dispatch", **labels)
